@@ -68,12 +68,19 @@ def main() -> None:
         "modules": {},
         "failures": failures,
     }
-    try:
-        import jax
-        artifact["jax"] = jax.__version__
-        artifact["devices"] = [str(d) for d in jax.devices()]
-    except Exception:  # noqa: BLE001
-        pass
+    # every result names the device it ran on; an unreadable device is a
+    # failed run, not an anonymous one
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    devices = jax.devices()
+    artifact["jax"] = jax.__version__
+    artifact["device"] = {"platform": devices[0].platform,
+                          "kind": devices[0].device_kind,
+                          "count": len(devices)}
+    artifact["devices"] = [str(d) for d in devices]
+    print(f"# device: {artifact['device']}", flush=True)
     for name in names:
         print(f"# === benchmarks.{name} ===", flush=True)
         t0 = time.time()

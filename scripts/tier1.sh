@@ -12,12 +12,6 @@
 # analysis.md). bench_smoke.sh also censuses the int32 jaxpr and fails on
 # any multiply, so benchmark bit-rot is caught here, not at release time.
 #
-# The suite runs as a few pytest processes, not one: this container's
-# jaxlib 0.4.37 XLA CPU compiler segfaults after ~90 heavy compilations
-# in a single process (see CHANGES.md PR 6 note — a pristine-seed
-# worktree crashes identically, so it is environmental, not a
-# regression). Each group keeps -x fail-fast semantics; extra args are
-# passed to every group.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -33,37 +27,10 @@ else
   echo "tier1: WARNING: ruff not installed; skipping lint gate" >&2
 fi
 
-# test groups: compile-heavy files spread out so no single process crosses
-# the XLA CPU segfault threshold
-group1=(tests/test_fixed.py tests/test_golden.py tests/test_quant.py)
-group2=(tests/test_streaming_parity.py tests/test_kernels.py
-        tests/test_analysis.py)
-group3=(tests/test_pipeline.py tests/test_ssm.py tests/test_ir.py)
-group4=(tests/test_serving.py tests/test_slot_surgery.py
-        tests/test_server_contract.py tests/test_async_serving.py)
-group5=(tests/test_archs.py tests/test_checkpoint.py
-        tests/test_distributed.py tests/test_filterbank.py
-        tests/test_hlo_cost.py tests/test_kernel_machine.py
-        tests/test_mp.py tests/test_system.py)
-group6=(tests/test_verilog.py tests/test_ir_artifacts.py)
-
-# coverage guard: every tests/test_*.py must appear in exactly one group,
-# so a new test file can't silently drop out of tier-1
-all_grouped=$(printf '%s\n' "${group1[@]}" "${group2[@]}" "${group3[@]}" \
-                     "${group4[@]}" "${group5[@]}" "${group6[@]}" | sort)
-all_files=$(ls tests/test_*.py | sort)
-if [ "$all_grouped" != "$all_files" ]; then
-  echo "tier1: test group lists are out of sync with tests/test_*.py:" >&2
-  diff <(echo "$all_grouped") <(echo "$all_files") >&2 || true
-  exit 1
-fi
-
-python -m pytest -x -q "${group1[@]}" "$@"
-python -m pytest -x -q "${group2[@]}" "$@"
-python -m pytest -x -q "${group3[@]}" "$@"
-python -m pytest -x -q "${group4[@]}" "$@"
-python -m pytest -x -q "${group5[@]}" "$@"
-python -m pytest -x -q "${group6[@]}" "$@"
+# the whole suite in one pytest call, on the CPU (Pallas in interpret
+# mode; tests/test_chip_compile.py compiles the hot path for a described
+# v5e); extra args are passed through
+JAX_PLATFORMS=cpu python -m pytest -x -q "$@"
 
 # static verification gate: op-legality + worst-case interval proof +
 # determinism lint over the deployed integer programs (full config;

@@ -7,7 +7,7 @@ every register fits its declared bitwidth. This package proves both
 properties on the traced integer programs instead of sampling them:
 
 ``traverse``
-    One shared jaxpr walk (recursing through ``pjit``, ``scan``, ``cond``,
+    One shared jaxpr walk (recursing through ``jit``, ``scan``, ``cond``,
     ``while``, ``pallas_call`` and friends) that every pass — and the
     benchmark census — runs on, so the gate and the numbers can't diverge.
 ``legality``

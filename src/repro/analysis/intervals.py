@@ -48,6 +48,8 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from repro.analysis import traverse
+
 INF = float("inf")
 
 
@@ -535,9 +537,7 @@ class _Analyzer:
             env[v] = val
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if name in ("pjit", "closed_call", "custom_vjp_call",
-                        "custom_jvp_call", "custom_vjp_call_jaxpr",
-                        "remat", "checkpoint"):
+            if name in traverse.CALL_PRIMS or name == traverse.VJP_JAXPR_PRIM:
                 self._eval_call(eqn, env, path)
             elif name == "scan":
                 self._eval_scan(eqn, env, path)

@@ -1,7 +1,7 @@
 """The shared jaxpr traversal every analysis pass runs on.
 
 A traced program is a tree of jaxprs: the top level plus sub-jaxprs hidden
-inside higher-order primitives (``pjit``/call wrappers, ``scan``/``while``
+inside higher-order primitives (``jit``/call wrappers, ``scan``/``while``
 loops, ``cond`` branches, ``pallas_call`` kernel bodies). Every pass in
 this package — and the benchmark census in ``benchmarks/hardware_cost.py``
 — walks that tree through ONE function (:func:`walk`), so the legality
@@ -33,7 +33,7 @@ from __future__ import annotations
 from typing import Callable, Iterator
 
 # call-like primitives whose sub-jaxpr runs exactly once per invocation
-CALL_PRIMS = ("pjit", "closed_call", "custom_vjp_call", "custom_jvp_call",
+CALL_PRIMS = ("jit", "closed_call", "custom_vjp_call", "custom_jvp_call",
               "remat", "checkpoint")
 
 # jax 0.4.x names the staged-out custom-vjp primitive differently; the
@@ -73,7 +73,7 @@ def eqn_source(eqn) -> str:
     eqns in reports): ``file.py:123 (fn_name)`` when available."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             fname = frame.file_name.rsplit("/", 1)[-1]
             return f"{fname}:{frame.start_line} ({frame.function_name})"

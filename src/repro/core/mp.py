@@ -353,6 +353,98 @@ def _mp_dot_fast(x: jax.Array, w: jax.Array, gamma, solver: str) -> jax.Array:
     raise ValueError(f"unknown MP solver: {solver!r}")
 
 
+# ---------------------------------------------------------------------------
+# the same solvers over an unrolled operand list (Pallas kernel bodies)
+# ---------------------------------------------------------------------------
+#
+# A TPU kernel cannot hold a (rows, n, M) window tensor with the tap axis
+# minor (Mosaic refuses the gather that builds it), so the streaming
+# kernels keep one (rows, n) array per tap. The functions below run the
+# SAME per-element operations as ``mpabs_newton`` / ``mpabs`` /
+# ``_mp_dot_fast`` over such a list — max chains for ``jnp.max``, integer
+# counts for the comparison sums and :func:`tree_sum_terms` for the
+# fixed-tree sums — so every output is bit-for-bit the array form's.
+
+
+def tree_sum_terms(terms: list) -> jax.Array:
+    """:func:`tree_sum` over a list of equally shaped operands (the list is
+    the summed axis): the same zero-padding to a power of two and the same
+    pairwise halving tree, so the result is bitwise ``tree_sum``'s."""
+    terms = list(terms)
+    p = 1
+    while p < len(terms):
+        p <<= 1
+    terms += [jnp.zeros_like(terms[0])] * (p - len(terms))
+    while len(terms) > 1:
+        terms = [terms[i] + terms[i + 1] for i in range(0, len(terms), 2)]
+    return terms[0]
+
+
+def _count_terms(masks: list) -> jax.Array:
+    """Integer count of true entries across a list of boolean arrays."""
+    k = masks[0].astype(jnp.int32)
+    for m in masks[1:]:
+        k = k + m.astype(jnp.int32)
+    return k
+
+
+def _mpabs_newton_terms(us: list, gamma, iters: int = DEFAULT_NEWTON_ITERS):
+    """``mpabs_newton`` with the operand axis unrolled into ``us``."""
+    dt = us[0].dtype
+    gamma = jnp.asarray(gamma, dtype=dt)
+    a = [jnp.abs(u) for u in us]
+    z = a[0]
+    for t in a[1:]:
+        z = jnp.maximum(z, t)
+    z = z - gamma
+
+    def body(_, z):
+        s = (tree_sum_terms([jnp.maximum(t - z, 0) for t in a])
+             + tree_sum_terms([jnp.maximum(-t - z, 0) for t in a]))
+        k = (_count_terms([t > z for t in a])
+             + _count_terms([-t > z for t in a])).astype(dt)
+        return z + (s - gamma) / jnp.maximum(k, 1.0)
+
+    return jax.lax.fori_loop(0, iters, body, z)
+
+
+def _mpabs_bisect_terms(us: list, gamma,
+                        iters: int = DEFAULT_BISECT_ITERS) -> jax.Array:
+    """``mpabs(exact=False)`` with the operand axis unrolled into ``us``."""
+    dt = us[0].dtype
+    gamma = jnp.asarray(gamma, dtype=dt)
+    hi = jnp.abs(us[0])
+    for u in us[1:]:
+        hi = jnp.maximum(hi, jnp.abs(u))
+    lo = hi - gamma
+
+    def body(_, state):
+        lo, hi = state
+        mid = (lo + hi) * jnp.asarray(0.5, dt)
+        h = (tree_sum_terms([jnp.maximum(u - mid, 0) for u in us])
+             + tree_sum_terms([jnp.maximum(-u - mid, 0) for u in us]))
+        too_low = h > gamma
+        lo = jnp.where(too_low, mid, lo)
+        hi = jnp.where(too_low, hi, mid)
+        return lo, hi
+
+    lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    return (lo + hi) * jnp.asarray(0.5, dt)
+
+
+def mp_dot_fast_terms(xs: list, ws: list, gamma, solver: str) -> jax.Array:
+    """``_mp_dot_fast`` over unrolled operands: ``xs[k]`` is the k-th
+    window column (any shape), ``ws[k]`` its tap (scalar or broadcastable).
+    Bitwise equal to ``_mp_dot_fast(stack(xs, -1), stack(ws), ...)``."""
+    us = [w + x for x, w in zip(xs, ws)]
+    vs = [w - x for x, w in zip(xs, ws)]
+    if solver == "newton":
+        return _mpabs_newton_terms(us, gamma) - _mpabs_newton_terms(vs, gamma)
+    if solver == "bisect":
+        return _mpabs_bisect_terms(us, gamma) - _mpabs_bisect_terms(vs, gamma)
+    raise ValueError(f"unknown MP solver: {solver!r}")
+
+
 def mp_conv1d_bank(
     x: jax.Array,
     H: jax.Array,

@@ -1,7 +1,7 @@
 """Lower a traced integer jaxpr into the typed op-stream IR.
 
 The lowering is deliberately 1:1 with the traced program: every leaf jaxpr
-equation becomes exactly one IR instruction (``pjit``/call wrappers are
+equation becomes exactly one IR instruction (``jit``/call wrappers are
 inlined with no instruction, ``scan`` becomes one ``loop`` with a body
 region, ``pallas_call`` one ``grid`` region), so the IR census
 (``repro.ir.census``) reproduces the jaxpr-walk census numbers EXACTLY —
@@ -18,7 +18,7 @@ and the builder runs the worst-case interval pass over the SAME
 ``ClosedJaxpr`` object, then keys each equation's proven interval /
 minimal bitwidth by ``(path, id(eqn))`` — the builder's recursion
 replicates the analyzer's path strings exactly (``""`` at top,
-``/pjit`` for inlined calls, ``/scan[N]`` for loop bodies,
+``/jit`` for inlined calls, ``/scan[N]`` for loop bodies,
 ``/pallas_call`` for grid kernels), so every IR register carries the fact
 the static proof established for its defining equation.
 
@@ -34,6 +34,7 @@ import math
 
 import numpy as np
 
+from repro.analysis.traverse import CALL_PRIMS, VJP_JAXPR_PRIM
 from repro.ir.isa import Instr, Program, Reg, Region, Rom
 
 # leaf jax primitives with a direct IR opcode (same-arity, srcs = invars)
@@ -58,8 +59,7 @@ _DIRECT = {
     "program_id": "program_id", "num_programs": "num_programs",
 }
 
-_CALL_PRIMS = ("pjit", "closed_call", "custom_vjp_call", "custom_jvp_call",
-               "custom_vjp_call_jaxpr", "remat", "checkpoint")
+_CALL_PRIMS = CALL_PRIMS + (VJP_JAXPR_PRIM,)
 
 
 class BuildError(ValueError):

@@ -174,31 +174,155 @@ def _fir_mp_bank_kernel(gamma_ref, x_ref, h_ref, out_ref, *, iters, M,
 
 
 # ---------------------------------------------------------------------------
+# lane data movement shared by the streaming kernels
+# ---------------------------------------------------------------------------
+#
+# Mosaic (the TPU's Pallas compiler) lowers static lane slices,
+# concatenation, iota and selects, but no lane gather and no strided lane
+# slice. The streaming kernels therefore build every FIR window, the ÷2
+# decimator's windows and the per-slot delay-line slide from static lane
+# shifts plus per-row selects. These move exactly the values the XLA
+# session step gathers, so results stay bit-for-bit the XLA path's, and
+# the integer kernel's datapath stays shift/add/compare only.
+
+
+def _shl(x, s: int):
+    """``out[:, j] = x[:, j + s]``, zero-filled past the end (static s)."""
+    if s == 0:
+        return x
+    rows, w = x.shape
+    if s >= w:
+        return jnp.zeros_like(x)
+    return jnp.concatenate([x[:, s:], jnp.zeros((rows, s), x.dtype)], axis=1)
+
+
+def _lane_tree_sum(h, n: int):
+    """``mp.tree_sum`` of lanes ``[0, n)`` of ``h`` (n a power of two) as a
+    (rows, 1) column. Step t adds lane ``i + 2**t`` into lane ``i``, so lane
+    0 accumulates exactly the pairwise halving tree of ``tree_sum``."""
+    s = 1
+    while s < n:
+        h = h + _shl(h, s)
+        s <<= 1
+    return h[:, :1]
+
+
+def _compact_even(x, n: int):
+    """``out[:, i] = x[:, 2 * i]`` for ``i < n``: a log-depth compaction.
+
+    Before stage b, element i sits at lane ``2i - (i mod 2**b)``; stage b
+    moves it left by ``2**b`` when bit b of i is set. Positions stay
+    strictly increasing, so no two elements ever meet, and whether the
+    element at lane p exists and moves is a function of p alone."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    b = 0
+    while (1 << b) < n:
+        s = 1 << b
+        src = lane + s
+        moves = (jnp.bitwise_and(src, 2 * s - 1) < s) & (
+            jnp.bitwise_and(jnp.right_shift(src, b + 1), 1) == 1)
+        x = jnp.where(moves, _shl(x, s), x)
+        b += 1
+    return x
+
+
+def _slide(x, v, vmax: int):
+    """Per-row left shift ``out[r, j] = x[r, j + v[r]]`` for ``v`` (rows, 1)
+    in ``[0, vmax]``: a barrel shifter of static shifts and selects."""
+    b = 0
+    while (1 << b) <= vmax:
+        bit = jnp.bitwise_and(jnp.right_shift(v, b), 1) == 1
+        x = jnp.where(bit, _shl(x, 1 << b), x)
+        b += 1
+    return x
+
+
+def _fir_windows(buf, M: int, n: int) -> list:
+    """FIR windows as M lane slices: ``w[k][:, j] = buf[:, j + k]``."""
+    return [buf[:, k:k + n] for k in range(M)]
+
+
+def _decim_windows(buf, start, M: int, n: int) -> list:
+    """÷2 decimator windows: ``w[m][:, j] = buf[:, start + 2j + m]`` for
+    ``j < n``, with the per-row phase ``start`` (rows, 1) in {0, 1}.
+
+    The phase is a select between the buffer and its 1-lane shift; the
+    stride-2 pick becomes two compactions (even and odd lanes), after which
+    tap m is a static slice of one of them."""
+    x = jnp.where(start == 1, _shl(buf, 1), buf)
+    need = n + (M - 1) // 2
+    even = _compact_even(x, need)
+    odd = _compact_even(_shl(x, 1), need)
+    return [(odd if m & 1 else even)[:, m >> 1:(m >> 1) + n]
+            for m in range(M)]
+
+
+def _slide_delay(delay, blk, v, T1: int, LB: int):
+    """The delay line after a block: the last T1 of ``[delay, blk[:v]]``,
+    i.e. ``[delay, blk][v:v + T1]`` per row (v = 0 keeps it bit-identical)."""
+    return _slide(jnp.concatenate([delay, blk], axis=1), v, LB)[:, :T1]
+
+
+def _add_column(part, f, s):
+    """``part[:, f] += s`` for a dynamic column f: a lane-masked select, so
+    every other column keeps its bits."""
+    col = jax.lax.broadcasted_iota(jnp.int32, part.shape, 1) == f
+    return jnp.where(col, part + s, part)
+
+
+def _stream_specs(bs, LB, T1, F, emit_next):
+    """BlockSpecs shared by both streaming kernels: slot-block rows, the
+    signal block per chunk block, whole-row state, and the tap ROMs and
+    gamma whole in SMEM (read as scalars)."""
+    row = lambda w: pl.BlockSpec((bs, w), lambda i, b, f: (i, 0))
+    in_specs = [
+        pl.BlockSpec((bs, LB), lambda i, b, f: (i, b)),   # signal
+        row(1),                                           # valid counts
+        row(1),                                           # decim phase
+        row(T1),                                          # delay line
+        row(F),                                           # accumulators
+        row(1),                                           # running amax
+    ]
+    out_specs = [row(F), row(T1), row(1)]
+    if emit_next:
+        out_specs.append(pl.BlockSpec((bs, LB // 2),
+                                      lambda i, b, f: (i, b)))
+    return in_specs, out_specs
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+# scratch is carried across grid steps -> every axis must iterate
+# sequentially on TPU (no parallel partitioning of the grid)
+_SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
+
+
+# ---------------------------------------------------------------------------
 # fir_mp_stream: stateful session-step kernel
 # ---------------------------------------------------------------------------
 
 
-def _fir_mp_stream_kernel(gamma_ref, x_ref, n_ref, start_ref, delay_ref,
-                          acc_ref, amax_ref, h_ref, lp_ref, *refs,
+def _fir_mp_stream_kernel(gamma_ref, h_ref, lp_ref, x_ref, n_ref, start_ref,
+                          delay_ref, acc_ref, amax_ref, *refs,
                           solver, scale, emit_next, update_amax,
                           T1, M, M_lp, LB):
     """One grid step of the streaming octave kernel.
 
     Grid is (slot_block, chunk_block, filter) with filter INNERMOST: the
     (bs, LB) signal block's index map is constant across the F filter steps,
-    so Pallas keeps it VMEM-resident and only the (1, M) tap row is
-    re-fetched per filter (same trick as fir_mp_bank). The slot state —
-    FIR delay line, per-band partial accumulators, running amax — lives in
-    VMEM scratch and is carried across the chunk_block axis: the chunk
-    streams through VMEM block by block with NO per-block HBM state
-    round-trip; state is read once at grid start and written once at the
-    final step.
+    so Pallas keeps it VMEM-resident while the kernel reads filter f's taps
+    from the whole (F, M) tap ROM in SMEM. The slot state — FIR delay line,
+    per-band partial accumulators, running amax — lives in VMEM scratch and
+    is carried across the chunk_block axis: the chunk streams through VMEM
+    block by block with NO per-block HBM state round-trip; state is read
+    once at grid start and written once at the final step.
 
     Bit-parity with the XLA session step is by construction: the same
-    ``mp._mp_dot_fast`` solver runs on the same window values (per-row
-    minor-axis reductions are leading-shape independent), and the HWR sums
-    use the shared ``accumulate_block_len`` blocking, added in ascending
-    block order exactly like ``filterbank.hwr_accumulate``.
+    solver (``mp.mp_dot_fast_terms``, the tap-unrolled ``_mp_dot_fast``)
+    runs on the same window values, and the HWR sums use the shared
+    ``accumulate_block_len`` blocking with ``tree_sum``'s tree, added in
+    ascending block order exactly like ``filterbank.hwr_accumulate``.
     """
     if emit_next:
         out_acc_ref, out_delay_ref, out_amax_ref, out_next_ref = refs[:4]
@@ -219,8 +343,9 @@ def _fir_mp_stream_kernel(gamma_ref, x_ref, n_ref, start_ref, delay_ref,
         amax_s[...] = amax_ref[...]
 
     blk = x_ref[...]                              # (bs, LB)
-    nv = n_ref[...][:, 0]                         # (bs,) valid counts
-    gamma = gamma_ref[0, 0]
+    nv = n_ref[...]                               # (bs, 1) valid counts
+    gamma = gamma_ref[0]
+    delay = delay_s[...]                          # (bs, T1)
 
     if update_amax:
         # running amax: invalid tails were zeroed upstream, and the padded
@@ -233,17 +358,13 @@ def _fir_mp_stream_kernel(gamma_ref, x_ref, n_ref, start_ref, delay_ref,
                 jnp.max(jnp.abs(blk), axis=-1, keepdims=True))
 
     # --- band-pass filter f over this block -------------------------------
-    hist = delay_s[:, T1 - (M - 1):] if M > 1 else delay_s[:, T1:]
-    bufv = jnp.concatenate([hist, blk], axis=1)   # (bs, M-1+LB)
-    idx = (jax.lax.broadcasted_iota(jnp.int32, (LB, M), 0)
-           + jax.lax.broadcasted_iota(jnp.int32, (LB, M), 1))
-    win = bufv[:, idx]                            # (bs, LB, M) windows
-    h = h_ref[...][0, ::-1]                       # conv tap order, as in XLA
-    y = mp_mod._mp_dot_fast(win, h, gamma, solver)
+    bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
+    taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
+    y = mp_mod.mp_dot_fast_terms(_fir_windows(bufv, M, LB), taps, gamma,
+                                 solver)
     pos = b * LB + jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
-    hwr = jnp.where(pos < nv[:, None], jnp.maximum(y, 0.0), 0.0)
-    part_s[pl.ds(f, 1), :] = (part_s[pl.ds(f, 1), :]
-                              + mp_mod.tree_sum(hwr)[None, :])
+    hwr = jnp.where(pos < nv, jnp.maximum(y, 0.0), 0.0)
+    part_s[...] = _add_column(part_s[...], f, _lane_tree_sum(hwr, LB))
 
     @pl.when(f == F - 1)
     def _block_tail():
@@ -252,26 +373,20 @@ def _fir_mp_stream_kernel(gamma_ref, x_ref, n_ref, start_ref, delay_ref,
         # phase) is constant across blocks; kept j of block b lands at
         # out position b*LB/2 + j.
         if emit_next:
-            histl = (delay_s[:, T1 - (M_lp - 1):] if M_lp > 1
-                     else delay_s[:, T1:])
-            bufl = jnp.concatenate([histl, blk], axis=1)
-            widx = (2 * jax.lax.broadcasted_iota(jnp.int32, (LB // 2, M_lp), 0)
-                    + jax.lax.broadcasted_iota(jnp.int32, (LB // 2, M_lp), 1))
-            stv = start_ref[...][:, 0]            # per-slot phase in {0, 1}
-            winl = jax.vmap(lambda r, s: r[s + widx])(bufl, stv)
-            lp = lp_ref[...][0, ::-1]
-            out_next_ref[...] = mp_mod._mp_dot_fast(winl, lp, gamma, solver)
+            bufl = jnp.concatenate([delay[:, T1 - (M_lp - 1):], blk], axis=1)
+            winl = _decim_windows(bufl, start_ref[...], M_lp, LB // 2)
+            lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
+            out_next_ref[...] = mp_mod.mp_dot_fast_terms(winl, lp, gamma,
+                                                         solver)
         # slide the delay line by this block's VALID sample count; a
         # zero-valid (masked/inert) slot slides by 0 and keeps its
         # registers bit-identical.
         v = jnp.clip(nv - b * LB, 0, LB)
-        bufd = jnp.concatenate([delay_s[...], blk], axis=1)
-        delay_s[...] = jax.vmap(
-            lambda r, s: jax.lax.dynamic_slice(r, (s,), (T1,)))(bufd, v)
+        delay_s[...] = _slide_delay(delay, blk, v, T1, LB)
 
     @pl.when((b == NB - 1) & (f == F - 1))
     def _flush():
-        out_acc_ref[...] = acc_ref[...] + part_s[...].T * scale
+        out_acc_ref[...] = acc_ref[...] + part_s[...] * scale
         out_delay_ref[...] = delay_s[...]
         out_amax_ref[...] = amax_s[...]
 
@@ -324,53 +439,34 @@ def fir_mp_stream_octave(
     delay_p = jnp.pad(delay, ((0, s_pad), (0, 0)))
     acc_p = jnp.pad(acc, ((0, s_pad), (0, 0)))
     amax2 = pad1(amax.astype(dt))[:, None]
-    H = H.astype(dt)
-    lp2 = lp.astype(dt)[None, :]
-    gamma_arr = jnp.asarray(gamma, dtype=dt).reshape(1, 1)
+    gamma1 = jnp.asarray(gamma, dtype=dt).reshape(1)
 
     out_shape = [
         jax.ShapeDtypeStruct((Sp, F), dt),             # acc'
         jax.ShapeDtypeStruct((Sp, T1), dt),            # delay'
         jax.ShapeDtypeStruct((Sp, 1), dt),             # amax'
     ]
-    out_specs = [
-        pl.BlockSpec((bs, F), lambda i, b, f: (i, 0)),
-        pl.BlockSpec((bs, T1), lambda i, b, f: (i, 0)),
-        pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),
-    ]
     if emit_next:
         out_shape.append(jax.ShapeDtypeStruct((Sp, NB * (LB // 2)), dt))
-        out_specs.append(pl.BlockSpec((bs, LB // 2), lambda i, b, f: (i, b)))
+    in_specs, out_specs = _stream_specs(bs, LB, T1, F, emit_next)
 
     outs = pl.pallas_call(
         functools.partial(_fir_mp_stream_kernel, solver=solver, scale=scale,
                           emit_next=emit_next, update_amax=update_amax,
                           T1=T1, M=M, M_lp=M_lp, LB=LB),
         grid=(Sp // bs, NB, F),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, b, f: (0, 0)),     # gamma
-            pl.BlockSpec((bs, LB), lambda i, b, f: (i, b)),   # signal
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # valid counts
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # decim phase
-            pl.BlockSpec((bs, T1), lambda i, b, f: (i, 0)),   # delay line
-            pl.BlockSpec((bs, F), lambda i, b, f: (i, 0)),    # accumulators
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # running amax
-            pl.BlockSpec((1, M), lambda i, b, f: (f, 0)),     # BP tap row
-            pl.BlockSpec((1, M_lp), lambda i, b, f: (0, 0)),  # LP taps
-        ],
+        in_specs=[_SMEM, _SMEM, _SMEM] + in_specs,   # gamma, BP taps, LP
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
-            pltpu.VMEM((F, bs), dt),     # per-band partial accumulators
+            pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
             pltpu.VMEM((bs, 1), dt),     # running amax
         ],
-        # scratch is carried across grid steps -> every axis must iterate
-        # sequentially on TPU (no parallel partitioning of the grid)
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(gamma_arr, xp, n2, start2, delay_p, acc_p, amax2, H, lp2)
+    )(gamma1, H.astype(dt), lp.astype(dt), xp, n2, start2, delay_p, acc_p,
+      amax2)
 
     acc_o = outs[0][:S]
     delay_o = outs[1][:S]
@@ -570,8 +666,19 @@ def fir_mp_bank_q_pallas(
     return out[:, :B, :N]
 
 
-def _fir_mp_stream_q_kernel(x_ref, n_ref, start_ref, delay_ref, acc_ref,
-                            amax_ref, h_ref, lp_ref, *refs,
+def _fxp_mp_dot_ops(xs, ws, *, gamma_q, iters, spec):
+    """``fixed.fxp_mp_dot`` over unrolled operands (``xs[k]`` the k-th
+    window column, ``ws[k]`` its tap): operand sums saturate onto ``spec``,
+    then the two integer bisections. Integer max and adds are
+    order-free, so this is bit-for-bit the array form."""
+    us = [jnp.clip(w + x, spec.qmin, spec.qmax) for x, w in zip(xs, ws)]
+    vs = [jnp.clip(w - x, spec.qmin, spec.qmax) for x, w in zip(xs, ws)]
+    return (_fxp_mpabs_ops(us, gamma_q, iters)
+            - _fxp_mpabs_ops(vs, gamma_q, iters))
+
+
+def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
+                            delay_ref, acc_ref, amax_ref, *refs,
                             stage, next_qmin, next_qmax, emit_next,
                             update_amax, T1, M, M_lp, LB):
     """One grid step of the INTEGER streaming octave kernel.
@@ -595,7 +702,8 @@ def _fir_mp_stream_q_kernel(x_ref, n_ref, start_ref, delay_ref, acc_ref,
       happens in-kernel, so y_next needs no post-processing.
 
     All gammas/iters/shifts/clamp bounds come from the compiled
-    ``fixed.OctaveStage`` — static ROM constants, never kernel operands.
+    ``fixed.OctaveStage`` — static ROM constants, never kernel operands;
+    the tap ROMs sit whole in SMEM and are read as scalars.
     """
     if emit_next:
         out_acc_ref, out_delay_ref, out_amax_ref, out_next_ref = refs[:4]
@@ -616,7 +724,8 @@ def _fir_mp_stream_q_kernel(x_ref, n_ref, start_ref, delay_ref, acc_ref,
         amax_s[...] = amax_ref[...]
 
     blk = x_ref[...]                              # (bs, LB) register codes
-    nv = n_ref[...][:, 0]                         # (bs,) valid counts
+    nv = n_ref[...]                               # (bs, 1) valid counts
+    delay = delay_s[...]                          # (bs, T1)
 
     if update_amax:
         # running max |code| telemetry (octave 0): invalid tails are zero
@@ -629,18 +738,16 @@ def _fir_mp_stream_q_kernel(x_ref, n_ref, start_ref, delay_ref, acc_ref,
                 jnp.max(jnp.abs(blk), axis=-1, keepdims=True))
 
     # --- band-pass filter f over this block (integer MP solve) ------------
-    hist = delay_s[:, T1 - (M - 1):] if M > 1 else delay_s[:, T1:]
-    bufv = jnp.concatenate([hist, blk], axis=1)   # (bs, M-1+LB)
-    idx = (jax.lax.broadcasted_iota(jnp.int32, (LB, M), 0)
-           + jax.lax.broadcasted_iota(jnp.int32, (LB, M), 1))
-    win = fx.rescale(bufv[:, idx], stage.sig_shift)    # onto the band grid
-    h = h_ref[...][0, ::-1]                       # conv tap order, as in XLA
-    y = fx.fxp_mp_dot(win, h, stage.gamma_bp, stage.iters_bp,
-                      stage.band_spec)
+    bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
+    win = [fx.rescale(w, stage.sig_shift)            # onto the band grid
+           for w in _fir_windows(bufv, M, LB)]
+    taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
+    y = _fxp_mp_dot_ops(win, taps, gamma_q=stage.gamma_bp,
+                        iters=stage.iters_bp, spec=stage.band_spec)
     pos = b * LB + jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
-    hwr = jnp.where(pos < nv[:, None], fx._relu(y), 0)
-    part_s[pl.ds(f, 1), :] = (part_s[pl.ds(f, 1), :]
-                              + jnp.sum(hwr, axis=-1)[None, :])
+    hwr = jnp.where(pos < nv, fx._relu(y), 0)
+    part_s[...] = _add_column(part_s[...], f,
+                              jnp.sum(hwr, axis=-1, keepdims=True))
 
     @pl.when(f == F - 1)
     def _block_tail():
@@ -649,31 +756,23 @@ def _fir_mp_stream_q_kernel(x_ref, n_ref, start_ref, delay_ref, acc_ref,
         # block b lands at out position b*LB/2 + j), then requantize onto
         # the next octave's register grid in-kernel.
         if emit_next:
-            histl = (delay_s[:, T1 - (M_lp - 1):] if M_lp > 1
-                     else delay_s[:, T1:])
-            bufl = jnp.concatenate([histl, blk], axis=1)
-            widx = (2 * jax.lax.broadcasted_iota(jnp.int32, (LB // 2, M_lp), 0)
-                    + jax.lax.broadcasted_iota(jnp.int32, (LB // 2, M_lp), 1))
-            stv = start_ref[...][:, 0]            # per-slot phase in {0, 1}
-            winl = fx.rescale(
-                jax.vmap(lambda r, s: r[s + widx])(bufl, stv),
-                stage.lp_sig_shift)
-            lp = lp_ref[...][0, ::-1]
-            kept = fx.fxp_mp_dot(winl, lp, stage.gamma_lp, stage.iters_lp,
-                                 stage.lp_spec)
+            bufl = jnp.concatenate([delay[:, T1 - (M_lp - 1):], blk], axis=1)
+            winl = [fx.rescale(w, stage.lp_sig_shift) for w in
+                    _decim_windows(bufl, start_ref[...], M_lp, LB // 2)]
+            lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
+            kept = _fxp_mp_dot_ops(winl, lp, gamma_q=stage.gamma_lp,
+                                   iters=stage.iters_lp, spec=stage.lp_spec)
             out_next_ref[...] = jnp.clip(
                 fx.rescale(kept, stage.lp_out_shift), next_qmin, next_qmax)
         # slide the delay line by this block's VALID sample count; a
         # zero-valid (masked/inert) slot slides by 0 and keeps its
         # registers bit-identical.
         v = jnp.clip(nv - b * LB, 0, LB)
-        bufd = jnp.concatenate([delay_s[...], blk], axis=1)
-        delay_s[...] = jax.vmap(
-            lambda r, s: jax.lax.dynamic_slice(r, (s,), (T1,)))(bufd, v)
+        delay_s[...] = _slide_delay(delay, blk, v, T1, LB)
 
     @pl.when((b == NB - 1) & (f == F - 1))
     def _flush():
-        out_acc_ref[...] = acc_ref[...] + fx.shift_left(part_s[...].T,
+        out_acc_ref[...] = acc_ref[...] + fx.shift_left(part_s[...],
                                                         stage.acc_shift)
         out_delay_ref[...] = delay_s[...]
         out_amax_ref[...] = amax_s[...]
@@ -721,12 +820,12 @@ def fir_mp_stream_octave_q(
     dt = x.dtype
 
     if emit_next:
-        lp2 = stage.lp_q.astype(dt)              # (1, M_lp)
+        lp = stage.lp_q[0].astype(dt)            # (M_lp,)
         next_qmin, next_qmax = int(next_spec.qmin), int(next_spec.qmax)
     else:
-        lp2 = jnp.zeros((1, 1), dt)
+        lp = jnp.zeros((1,), dt)
         next_qmin = next_qmax = 0
-    (_, M_lp) = lp2.shape
+    (M_lp,) = lp.shape
 
     xp = jnp.pad(x, ((0, s_pad), (0, NB * LB - L)))
     pad1 = lambda a: jnp.pad(a, ((0, s_pad),))
@@ -735,21 +834,15 @@ def fir_mp_stream_octave_q(
     delay_p = jnp.pad(delay, ((0, s_pad), (0, 0)))
     acc_p = jnp.pad(acc, ((0, s_pad), (0, 0)))
     amax2 = pad1(amax.astype(dt))[:, None]
-    H = stage.bp_q.astype(dt)
 
     out_shape = [
         jax.ShapeDtypeStruct((Sp, F), dt),             # acc'
         jax.ShapeDtypeStruct((Sp, T1), dt),            # delay'
         jax.ShapeDtypeStruct((Sp, 1), dt),             # amax'
     ]
-    out_specs = [
-        pl.BlockSpec((bs, F), lambda i, b, f: (i, 0)),
-        pl.BlockSpec((bs, T1), lambda i, b, f: (i, 0)),
-        pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),
-    ]
     if emit_next:
         out_shape.append(jax.ShapeDtypeStruct((Sp, NB * (LB // 2)), dt))
-        out_specs.append(pl.BlockSpec((bs, LB // 2), lambda i, b, f: (i, b)))
+    in_specs, out_specs = _stream_specs(bs, LB, T1, F, emit_next)
 
     outs = pl.pallas_call(
         functools.partial(_fir_mp_stream_q_kernel, stage=stage,
@@ -757,29 +850,17 @@ def fir_mp_stream_octave_q(
                           emit_next=emit_next, update_amax=update_amax,
                           T1=T1, M=M, M_lp=M_lp, LB=LB),
         grid=(Sp // bs, NB, F),
-        in_specs=[
-            pl.BlockSpec((bs, LB), lambda i, b, f: (i, b)),   # signal codes
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # valid counts
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # decim phase
-            pl.BlockSpec((bs, T1), lambda i, b, f: (i, 0)),   # delay line
-            pl.BlockSpec((bs, F), lambda i, b, f: (i, 0)),    # accumulators
-            pl.BlockSpec((bs, 1), lambda i, b, f: (i, 0)),    # running amax
-            pl.BlockSpec((1, M), lambda i, b, f: (f, 0)),     # BP tap row
-            pl.BlockSpec((1, M_lp), lambda i, b, f: (0, 0)),  # LP taps
-        ],
+        in_specs=[_SMEM, _SMEM] + in_specs,          # BP taps, LP taps
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
-            pltpu.VMEM((F, bs), dt),     # per-band partial accumulators
+            pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
             pltpu.VMEM((bs, 1), dt),     # running amax
         ],
-        # scratch is carried across grid steps -> every axis must iterate
-        # sequentially on TPU (no parallel partitioning of the grid)
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(xp, n2, start2, delay_p, acc_p, amax2, H, lp2)
+    )(stage.bp_q.astype(dt), lp, xp, n2, start2, delay_p, acc_p, amax2)
 
     acc_o = outs[0][:S]
     delay_o = outs[1][:S]

@@ -1,7 +1,7 @@
 """Public jit'd wrappers around the Pallas MP kernels.
 
 Responsibilities:
-  * interpret-mode fallback on CPU (this container) vs compiled on TPU;
+  * compiled on the TPU, interpret mode on the CPU, an error elsewhere;
   * shape canonicalization (leading batch dims flattened);
   * default block shapes from the committed autotune table
     (``stream_shapes.best_block_s``, refreshed by
@@ -33,7 +33,17 @@ from repro.kernels.stream_shapes import best_block_s
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpret mode on the CPU (tests and rehearsal
+    runs). Any other backend is an error: timing the Pallas interpreter on
+    an accelerator would report a device that never ran the kernel."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile only for the TPU (interpret mode on the "
+        f"CPU); the default backend is {backend!r}")
 
 
 # ---------------------------------------------------------------------------

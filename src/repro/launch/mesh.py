@@ -20,7 +20,7 @@ __all__ = ["make_production_mesh", "make_host_mesh", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -28,7 +28,15 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the partitioner places what the
+    code leaves unannotated (slot surgery's scatters included), as it did
+    before Explicit axes became the default."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 class HW:

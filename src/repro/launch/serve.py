@@ -80,7 +80,10 @@ def _serve_acoustic(args):
     jax.block_until_ready(state.acc)
     wall = time.time() - t0
     fed = args.streams * args.rounds
-    print(f"arch={ACOUSTIC_ARCH} streams={args.streams} "
+    dev = jax.devices()[0]
+    print(f"device={dev.platform}:{dev.device_kind} "
+          f"x{len(jax.devices())} "
+          f"arch={ACOUSTIC_ARCH} streams={args.streams} "
           f"chunk={args.chunk} ({args.chunk / fs * 1e3:.0f} ms) "
           f"rounds={args.rounds} shards={args.shards} "
           f"async={args.use_async} "
@@ -192,6 +195,8 @@ def main(argv=None):
                          "avoid saturating the demo)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch == ACOUSTIC_ARCH:
         return _serve_acoustic(args)
     return _serve_decode(args)

@@ -82,7 +82,8 @@ class StreamRouter:
             raise ValueError("num_shards must be >= 1")
         self.num_shards = num_shards
         self.pipeline = pipeline
-        step = server_kw.pop("step_fn", None) or make_batched_step(pipeline)
+        step = server_kw.pop("step_fn", None) or make_batched_step(
+            pipeline, server_kw.get("mesh"))
         self._shards = []
         for k in range(num_shards):
             ck = None

@@ -65,15 +65,21 @@ def _batched_step(pipe: InFilterPipeline, state: SessionState,
     return state, p
 
 
-def make_batched_step(pipeline: InFilterPipeline):
+def make_batched_step(pipeline: InFilterPipeline, mesh=None):
     """Compile the donated-state session step for ``pipeline``.
 
     Returns a callable ``(pipe, state, chunk, valid) -> (state, p)`` with a
-    uniform signature across numerics modes. A ``StreamServer`` builds one
-    per instance by default; pass the SAME callable to several servers
-    (``step_fn=``) to share one compile cache across shards — the
-    ``StreamRouter`` does exactly that, so N shards cost one compile per
-    chunk bucket, not N.
+    uniform signature across numerics modes; its ``lower`` takes the same
+    arguments and gives the AOT-lowered step (for its compiled text). A
+    ``StreamServer`` builds one per instance by default; pass the SAME
+    callable to several servers (``step_fn=``) to share one compile cache
+    across shards — the ``StreamRouter`` does exactly that, so N shards
+    cost one compile per chunk bucket, not N.
+
+    With ``mesh`` the step runs under ``shard_map`` over the slot axis
+    (the mesh's data axes): every op of the step is row-parallel, so each
+    device advances its own slots — Pallas kernels included — with no
+    collective, and the results are bitwise those of one device.
     """
     if pipeline.config.numerics == "fixed":
         # the integer program lowers HOST-side (concrete ROMs/shift
@@ -82,13 +88,34 @@ def make_batched_step(pipeline: InFilterPipeline):
         # and jit a closure over the concrete pipeline: the step's only
         # traced inputs are the donated integer registers + the chunk.
         pipeline.fixed_program()
-        fixed_step = jax.jit(
-            lambda state, chunk, valid: _batched_step(
-                pipeline, state, chunk, valid),
-            donate_argnums=(0,))
-        return lambda pipe, state, chunk, valid: \
-            fixed_step(state, chunk, valid)
-    return jax.jit(_batched_step, donate_argnums=(1,))
+        body = lambda state, chunk, valid: _batched_step(
+            pipeline, state, chunk, valid)
+        fixed_step = jax.jit(_slot_parallel(body, mesh, 0),
+                             donate_argnums=(0,))
+
+        def step(pipe, state, chunk, valid):
+            return fixed_step(state, chunk, valid)
+
+        step.lower = lambda pipe, state, chunk, valid: fixed_step.lower(
+            state, chunk, valid)
+        return step
+    return jax.jit(_slot_parallel(_batched_step, mesh, 1),
+                   donate_argnums=(1,))
+
+
+def _slot_parallel(fn, mesh, n_replicated: int):
+    """``fn`` under ``shard_map`` over the slot axis of ``mesh``: its first
+    ``n_replicated`` arguments are replicated, and every leaf of the other
+    arguments and of the outputs leads with the slot axis. No mesh: ``fn``."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import data_axes
+    slots = P(data_axes(mesh))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P(),) * n_replicated + (slots,) * 3,
+                         out_specs=slots, check_vma=False)
 
 
 class _StageBuffer:
@@ -155,8 +182,10 @@ class StreamServer:
                     auto-evicted to make room; ``None`` = any idle session.
     checkpoint_dir: where evicted sessions are parked; required for
                     eviction/reopen (without it a full server raises).
-    mesh:           optional ``jax.sharding.Mesh`` — shard the slot axis
-                    over the mesh's data axes.
+    mesh:           optional ``jax.sharding.Mesh`` (Auto axes) — shard the
+                    slot axis over the mesh's data axes; ``capacity`` must
+                    be a multiple of their size. A shared ``step_fn`` must
+                    be built with the same mesh.
     clock:          injectable monotonic clock (tests).
     coalesce_watermark: auto-dispatch threshold for the async queue: once
                     this many requests are pending, ``submit()`` launches
@@ -217,6 +246,12 @@ class StreamServer:
         self._valid_sharding = None
         if mesh is not None:
             from repro.distributed import sharding as sh
+            n_dp = int(np.prod([mesh.shape[a] for a in sh.data_axes(mesh)]))
+            if capacity % n_dp:
+                raise ValueError(
+                    f"capacity {capacity} must be a multiple of the mesh's "
+                    f"data-parallel size {n_dp}: each device holds an equal "
+                    "share of the slots")
             self._state = sh.shard_session(self._state, mesh)
             dp = sh.data_axes(mesh)
             self._chunk_sharding = jax.sharding.NamedSharding(
@@ -224,7 +259,7 @@ class StreamServer:
             self._valid_sharding = jax.sharding.NamedSharding(
                 mesh, sh.sanitize((dp,), (capacity,), mesh))
         self._step = step_fn if step_fn is not None \
-            else make_batched_step(pipeline)
+            else make_batched_step(pipeline, mesh)
         self._free = list(range(capacity - 1, -1, -1))  # pop() -> slot 0 first
         self._sessions: dict[str, Session] = {}
         self._manager = None

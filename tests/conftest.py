@@ -10,11 +10,47 @@ booleans / sampled_from / lists-of-floats.
 With hypothesis installed you get real shrinking and the registered "ci"
 profile (40 examples, no deadline); without it, the same number of
 deterministic examples.
+
+JAX's compiled programs are also dropped between tests once the worker
+holds many (see ``_release_compiled_programs``).
 """
 
+import gc
 import zlib
 
+import jax
 import numpy as np
+import pytest
+
+
+# a worker frees its compiled programs past this many memory mappings
+_MAPPINGS_BUDGET = 30_000
+
+
+def _mapping_count() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:          # no procfs: nothing to watch
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """Free JAX's compiled programs after a test once the worker holds many.
+
+    Each program XLA compiles for the CPU keeps its own memory mappings
+    until it is freed, and JAX's caches keep every program alive. A worker
+    that runs many compile-heavy tests (the interpret-mode Pallas paths
+    above all) otherwise reaches the kernel's per-process mapping limit
+    (``vm.max_map_count``, 65530 by default) and dies with a segfault
+    inside the compiler. Below the budget nothing is dropped, so tests
+    that share a compiled step keep sharing it.
+    """
+    yield
+    if _mapping_count() > _MAPPINGS_BUDGET:
+        jax.clear_caches()
+        gc.collect()
 
 try:
     from hypothesis import given, settings, strategies as st

@@ -24,6 +24,9 @@ What this file pins down:
 Randomization uses the hypothesis-or-fallback sampler in ``conftest.py``.
 """
 
+import os
+import tempfile
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,13 +235,20 @@ def test_async_coalescing_bitwise_matches_sync_feed(numerics, impl, seed):
 ])
 @settings(max_examples=2, deadline=None)
 @given(st.integers(0, 10 ** 9))
-def test_async_churn_register_exact_vs_sync(numerics, impl, tmp_path, seed):
+def test_async_churn_register_exact_vs_sync(numerics, impl, seed):
+    # a fresh checkpoint store per example: a function-scoped tmp_path
+    # would be shared by every example hypothesis draws
+    with tempfile.TemporaryDirectory() as ckpt:
+        _churn_register_exact_vs_sync(numerics, impl, seed, ckpt)
+
+
+def _churn_register_exact_vs_sync(numerics, impl, seed, ckpt):
     rng = np.random.default_rng(seed)
     ids = [f"s{i}" for i in range(4)]
     srv_sync = _server(numerics, impl, capacity=3,
-                       checkpoint_dir=str(tmp_path / "sync"))
+                       checkpoint_dir=os.path.join(ckpt, "sync"))
     srv_async = _server(numerics, impl, capacity=3,
-                        checkpoint_dir=str(tmp_path / "async"))
+                        checkpoint_dir=os.path.join(ckpt, "async"))
     open_set: set = set()
     tickets, expected = [], []
 

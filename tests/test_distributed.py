@@ -140,7 +140,8 @@ from repro.configs import get_smoke
 from repro.distributed import sharding as sh
 from repro.launch import specs as S
 from repro.launch.dryrun import lower_cell, roofline
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 cfg = dataclasses.replace(get_smoke("qwen3-8b"), remat=True)
 cell = S.ShapeCell("t", 128, 8, "train")
 with mesh:

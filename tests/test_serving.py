@@ -332,7 +332,8 @@ def test_session_specs_shard_slot_axis(pipe):
 
 
 def test_server_with_mesh_matches_unsharded(pipe):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(1, 1)          # Auto axes, as the server expects
     rng = np.random.default_rng(3)
     chunks = [rng.standard_normal(64).astype(np.float32) for _ in range(3)]
     plain = StreamServer(pipe, capacity=2)
