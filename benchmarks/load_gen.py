@@ -1,12 +1,11 @@
-"""Load generator: replay a fleet of logical sensor streams through the
-serving tier and measure streams/sec + per-feed latency percentiles.
+"""Fleet parity gate: replay a churning fleet of logical sensor streams
+through the serving tier along two paths that must decide alike.
 
 This is the acoupi traffic shape (PAPERS.md): many long-lived edge
 recorders phoning home with jittery, variable-length packets and churning
 lifetimes. The generator builds a DETERMINISTIC schedule (seeded rng,
-O(active-set) memory — ``--streams 1000000`` streams a million logical
-ids without materializing them) and replays the SAME schedule through two
-paths over an identically-configured ``StreamRouter``:
+O(active-set) memory) and replays the SAME schedule through two paths
+over an identically-configured ``StreamRouter``:
 
   sync   G independent callers per round, each paying a full synchronous
          ``feed()`` (dispatch + decision readback per caller);
@@ -16,23 +15,20 @@ paths over an identically-configured ``StreamRouter``:
 Decisions must match bit-for-bit between the paths — under churn
 (admission pressure auto-evicts LRU sessions to per-shard checkpoints;
 evicted streams reopen losslessly when they next emit), under request
-splitting, and under coalesced wave composition. ``--smoke`` runs a small
-traffic sample through BOTH numerics modes with that equality as a hard
-assert (wired into scripts/bench_smoke.sh -> tier1.sh); the full run
-asserts it too unless ``--no-parity``.
+splitting, and under coalesced wave composition; a mismatch is a hard
+assert. ``--smoke`` runs a small traffic sample through BOTH numerics
+modes (wired into scripts/bench_smoke.sh -> tier1.sh). Speed is measured
+on the chip by the benchmark in ``bench/`` (PERF.md), not here.
 
     PYTHONPATH=src python -m benchmarks.load_gen [--window 256] [--smoke]
-    PYTHONPATH=src python -m benchmarks.load_gen \
-        --streams 1000000 --rounds 2000 --paths async --no-parity
 
 Emits ``name,us_per_call,derived`` CSV rows like every other benchmark;
-``benchmarks.run`` folds them into BENCH_pipeline.json.
+none of them is a timing.
 """
 
 from __future__ import annotations
 
 import argparse
-import time
 
 import numpy as np
 
@@ -74,18 +70,14 @@ def _traffic(seed, n_streams, window, rounds, chunk_lo, chunk_hi,
         yield admits, burst, retires, evicts
 
 
-def _replay(router: StreamRouter, schedule, pool, groups: int, mode: str,
-            keep_decisions: bool):
-    """Drive one schedule through the router. Returns (decisions, latency
-    seconds per packet, packets fed, reopens)."""
-    decisions = {} if keep_decisions else None
-    lat: list = []
+def _replay(router: StreamRouter, schedule, pool, groups: int, mode: str):
+    """Drive one schedule through the router. Returns (decisions, packets
+    fed, reopens)."""
+    decisions = {}
     n_pkts = 0
     reopens = 0
 
     def record(results):
-        if decisions is None:
-            return
         for r in results:
             decisions[(r.session_id, r.samples_seen)] = (r.label,
                                                          r.confidence)
@@ -106,24 +98,12 @@ def _replay(router: StreamRouter, schedule, pool, groups: int, mode: str,
         parts = [reqs[g::groups] for g in range(groups)]
         if mode == "sync":
             for part in parts:
-                if not part:
-                    continue
-                t0 = time.perf_counter()
-                res = router.feed(part)
-                dt = time.perf_counter() - t0
-                lat.extend([dt] * len(part))
-                record(res)
+                if part:
+                    record(router.feed(part))
         else:
-            staged = []
-            for part in parts:
-                if not part:
-                    continue
-                staged.append((time.perf_counter(), part,
-                               router.submit(part)))
+            tickets = [router.submit(part) for part in parts if part]
             router.drain()
-            t_end = time.perf_counter()
-            for t0, part, ticket in staged:
-                lat.extend([t_end - t0] * len(part))
+            for ticket in tickets:
                 record(ticket.results)
         for sid in retires:
             if router.is_open(sid):
@@ -131,15 +111,10 @@ def _replay(router: StreamRouter, schedule, pool, groups: int, mode: str,
         for sid in evicts:
             if router.is_open(sid):
                 router.evict(sid)
-    return decisions, lat, n_pkts, reopens
+    return decisions, n_pkts, reopens
 
 
-def _pcts(lat_s):
-    us = np.asarray(lat_s) * 1e6
-    return float(np.percentile(us, 50)), float(np.percentile(us, 99))
-
-
-def _run_fleet(args, numerics: str, tag: str, hard_parity: bool):
+def _run_fleet(args, numerics: str, tag: str, hard_churn: bool):
     import tempfile
 
     from repro.configs.esc10_mp import make_pipeline
@@ -167,56 +142,23 @@ def _run_fleet(args, numerics: str, tag: str, hard_parity: bool):
                         args.life_lo, args.life_hi, args.emit_prob,
                         args.evict_prob)
 
-    keep = not args.no_parity
     out = {}
-    for mode in (("sync", "async") if args.paths == "both"
-                 else (args.paths,)):
-        router = make_router()
-        # warmup: compile the WHOLE bucket ladder off the clock, for every
-        # shard's server alike (they share one step, so one pass does it) —
-        # otherwise whichever path runs first eats the compile time and the
-        # speedup row measures cache luck, not pipelining
-        L = 16
-        while L <= args.max_chunk:
-            router.open("warm")
-            router.feed([("warm", pool[:L])])
-            router.close("warm")
-            L <<= 1
-        t0 = time.perf_counter()
-        dec, lat, n_pkts, reopens = _replay(
-            router, schedule(), pool, args.groups, mode, keep)
-        wall = time.perf_counter() - t0
-        p50, p99 = _pcts(lat)
-        out[mode] = (dec, wall, n_pkts, reopens)
-        row(f"load_gen.{mode}{tag}.W{args.window}.G{args.groups}",
-            wall / max(n_pkts, 1) * 1e6,
-            f"{n_pkts / max(wall, 1e-9):.0f} streams/s "
-            f"({n_pkts} packets, {reopens} evict-reopens)")
-        row(f"load_gen.latency.{mode}{tag}.W{args.window}", None,
-            f"p50={p50:.0f}us p99={p99:.0f}us")
-
-    if args.paths == "both":
-        (dec_s, wall_s, n, _), (dec_a, wall_a, _, _) = out["sync"], \
-            out["async"]
-        speedup = wall_s / max(wall_a, 1e-9)
-        bitwise = None
-        if keep:
-            bitwise = dec_s == dec_a   # exact: labels, confidences, counts
-        row(f"load_gen.async_speedup{tag}.W{args.window}.G{args.groups}",
-            None, f"speedup_vs_sync={speedup:.2f}x bitwise={bitwise}")
-        if keep and not bitwise:
-            raise AssertionError(
-                f"async/coalesced decisions != sync feed() decisions "
-                f"({numerics} numerics, {args.stream_impl}) — the bitwise "
-                "serving contract is violated")
-        if hard_parity:
-            assert bitwise
-            # the parity claim must have covered churn: at least one
-            # evicted stream must have come back through a checkpoint
-            assert out["async"][3] > 0, \
-                "smoke schedule exercised no evict->reopen churn"
-        return speedup
-    return None
+    for mode in ("sync", "async"):
+        out[mode] = _replay(make_router(), schedule(), pool, args.groups,
+                            mode)
+    (dec_s, n_pkts, _), (dec_a, _, reopens) = out["sync"], out["async"]
+    bitwise = dec_s == dec_a       # exact: labels, confidences, counts
+    row(f"load_gen.async_parity{tag}.W{args.window}.G{args.groups}", None,
+        f"bitwise={bitwise} ({n_pkts} packets, {reopens} evict-reopens)")
+    if not bitwise:
+        raise AssertionError(
+            f"async/coalesced decisions != sync feed() decisions "
+            f"({numerics} numerics, {args.stream_impl}) — the bitwise "
+            "serving contract is violated")
+    if hard_churn:
+        # the parity claim must have covered churn: at least one evicted
+        # stream must have come back through a checkpoint
+        assert reopens > 0, "smoke schedule exercised no evict->reopen churn"
 
 
 def main(argv=()):
@@ -227,7 +169,7 @@ def main(argv=()):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--streams", type=int, default=1024,
                     help="logical stream ids cycled through the window "
-                         "(schedule is O(window) memory: 10^6 works)")
+                         "(the schedule is O(window) memory)")
     ap.add_argument("--window", type=int, default=256,
                     help="max concurrently-active streams (= total slot "
                          "capacity across shards)")
@@ -249,11 +191,6 @@ def main(argv=()):
                     default="xla")
     ap.add_argument("--numerics", choices=["float", "fixed"],
                     default="float")
-    ap.add_argument("--paths", choices=["both", "sync", "async"],
-                    default="both")
-    ap.add_argument("--no-parity", action="store_true",
-                    help="skip decision recording/compare (million-stream "
-                         "throughput runs)")
     args = ap.parse_args(argv)
 
     if args.smoke:
@@ -265,13 +202,13 @@ def main(argv=()):
         args.evict_prob = 0.3   # make evict->reopen churn certain
         for nm in ("float", "fixed"):
             tag = "" if nm == "float" else ".fixed"
-            _run_fleet(args, nm, f".smoke{tag}", hard_parity=True)
+            _run_fleet(args, nm, f".smoke{tag}", hard_churn=True)
         print("load_gen --smoke: async == sync decisions (both numerics)",
               flush=True)
         return
 
     tag = "" if args.numerics == "float" else ".fixed"
-    _run_fleet(args, args.numerics, tag, hard_parity=False)
+    _run_fleet(args, args.numerics, tag, hard_churn=False)
 
 
 if __name__ == "__main__":

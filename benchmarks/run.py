@@ -19,13 +19,10 @@ benchmark results are a diffable file instead of scrollback. Modules:
   microbench           kernel reference timings
   pipeline_e2e         unified audio->decision pipeline: one-shot vs
                        streaming vs the seed per-filter path
-  serve_streams        slot-batched StreamServer vs naive per-stream
-                       step loop (+ async/coalesced feed vs sync callers,
-                       per-feed latency percentiles, quantized streaming
-                       parity)
-  load_gen             fleet load generator: churning logical streams
-                       through the sharded router, async vs sync paths,
-                       streams/s + p50/p99 + bitwise-parity gate
+  serve_streams        serving parity gates: async/coalesced vs sync
+                       feed, Pallas vs XLA step, streamed vs one-shot
+  load_gen             fleet parity gate: churning logical streams
+                       through the sharded router, async vs sync paths
 """
 
 from __future__ import annotations

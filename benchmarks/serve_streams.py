@@ -1,48 +1,38 @@
-"""Multi-stream serving throughput: slot-batched StreamServer vs a naive
-per-stream step() loop.
+"""Serving parity gates: the decisions of the slot-batched StreamServer
+along the paths that must agree.
 
-The ROADMAP north-star workload: thousands of concurrent sensor streams per
-chip. The naive baseline drives S independent one-stream cohorts through the
-jitted legacy ``step`` — S dispatches per round. The server packs the same S
-streams into one slot-batched ``SessionState`` and advances ALL of them with
-ONE donated-state compiled call per round (padding + per-slot valid counts),
-which is where the >=5x at S=256 comes from.
+* The async/coalescing front end: G callers' ``submit()``s resolved by
+  one ``drain()`` against the same G callers' synchronous ``feed()``s,
+  bit-for-bit in both numerics modes (a hard assert).
+* ``--stream-impl both``: the stateful Pallas streaming kernel against
+  the XLA session step on fresh servers, decisions and registers; a hard
+  assert under ``--numerics fixed`` (int Pallas == int XLA).
+* Streamed against one-shot decisions: exact equality under
+  ``--numerics fixed`` (a hard assert, static ADC grid); the quantized
+  float path's gap is reported, with the running amax seeded (a
+  calibrated/held stream).
 
-Also reports quantized streaming parity: with the running amax seeded (a
-calibrated/held stream), chunked session ``apply()`` must reproduce one-shot
-``predict()`` — the deployment-faithful semantics the old chunk-local amax
-could not deliver.
-
-``--stream-impl`` selects the session-step hot path ("xla" | "pallas" |
-"both"); "both" additionally reports pallas-vs-xla speedup and their
-bit-for-bit decision parity. ``--numerics fixed`` serves the bit-true
-int32 hardware twin instead of the float engine — there the "both" parity
-row is a HARD bitwise gate (int Pallas == int XLA registers and
-decisions), and the streaming-parity row compares streamed decisions
-against one-shot ``apply`` at exact equality. Off-TPU the Pallas kernels
-run in interpret mode, so CPU numbers measure wiring, not the
-VMEM-residency win — the >=1.5x target is a TPU measurement (see
-ROADMAP).
+Off-TPU the Pallas kernels run in interpret mode. Speed is measured on the
+chip by the benchmark in ``bench/`` (PERF.md), not here.
 
     PYTHONPATH=src python -m benchmarks.serve_streams [--slots 256] [--smoke]
 
-Emits ``name,us_per_call,derived`` CSV rows like every other benchmark.
+Emits ``name,us_per_call,derived`` CSV rows like every other benchmark;
+none of these rows is a timing.
 """
 
 from __future__ import annotations
 
 import argparse
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import row, time_fn
+from benchmarks.common import row
 from repro.configs.esc10_mp import make_pipeline
-from repro.core.pipeline import InFilterPipeline
 from repro.serving import StreamServer, make_batched_step
 
-ROUNDS = 2  # chunks per stream per timed call
+ROUNDS = 2  # chunks per stream
 
 
 def _pow2_at_least(n: int) -> int:
@@ -65,8 +55,8 @@ def main(argv=()):
                     help="tiny run for CI bit-rot checks")
     ap.add_argument("--stream-impl", choices=["xla", "pallas", "both"],
                     default="xla",
-                    help="session-step hot path; 'both' also reports the "
-                         "pallas-vs-xla speedup and decision parity")
+                    help="session-step hot path; 'both' also checks the "
+                         "pallas-vs-xla decision parity")
     ap.add_argument("--numerics", choices=["float", "fixed"],
                     default="float",
                     help="serving engine; 'fixed' serves the bit-true "
@@ -75,7 +65,6 @@ def main(argv=()):
     args = ap.parse_args(argv)
     S = 16 if args.smoke else args.slots
     CH = args.chunk
-    iters = 2 if args.smoke else 3
     primary_impl = "xla" if args.stream_impl == "both" else args.stream_impl
     nm = args.numerics
     tag = "" if nm == "float" else ".fixed"
@@ -86,128 +75,47 @@ def main(argv=()):
         return make_pipeline(smoke=True, stream_impl=impl, numerics=nm,
                              fixed_amax=4.0 if nm == "fixed" else None)
 
-    pipe = _pipe(primary_impl)
     rng = np.random.default_rng(0)
     audio = rng.standard_normal((S, ROUNDS * CH)).astype(np.float32)
-
-    # -- naive: per-stream serving, one jitted step + one host->device
-    # upload + one decision readback PER STREAM per packet (exactly what a
-    # stream-at-a-time server pays; the slot-batched server amortizes all
-    # three across S streams) ----------------------------------------------
-    if nm == "fixed":
-        # the integer program lowers host-side: jit a closure over the
-        # concrete pipeline (same shape as the server's donated step)
-        _step = jax.jit(lambda s, c: InFilterPipeline.step(pipe, s, c))
-        step = lambda p, s, c: _step(s, c)  # noqa: E731
-    else:
-        step = jax.jit(InFilterPipeline.step)
-
-    def naive():
-        states = [pipe.init_state(1) for _ in range(S)]
-        labels = None
-        for r in range(ROUNDS):
-            labels = []
-            for s in range(S):
-                chunk = jnp.asarray(audio[s:s + 1, r * CH:(r + 1) * CH])
-                states[s], p = step(pipe, states[s], chunk)
-                labels.append(int(np.asarray(p).argmax()))
-        return labels
-
-    us_naive = time_fn(naive, warmup=1, iters=iters)
-    row(f"serve_streams.naive_loop{tag}.S{S}xC{CH}", us_naive,
-        f"{S * ROUNDS / us_naive * 1e6:.0f} chunks/s")
-
-    # -- slot-batched server: ONE donated compiled call per round -----------
-    server = StreamServer(pipe, capacity=S, max_chunk=_pow2_at_least(CH))
     ids = [f"s{i:04d}" for i in range(S)]
-    for sid in ids:
-        server.open(sid)
-
-    def served():
-        res = None
-        for r in range(ROUNDS):
-            res = server.feed([(sid, audio[i, r * CH:(r + 1) * CH])
-                               for i, sid in enumerate(ids)])
-        jax.block_until_ready(server.state.acc)
-        return res
-
-    us_srv = time_fn(served, warmup=1, iters=iters)
-    row(f"serve_streams.stream_server{tag}.S{S}xC{CH}", us_srv,
-        f"speedup_vs_naive={us_naive / us_srv:.2f}x")
-    row(f"serve_streams.per_chunk_latency{tag}.S{S}", us_srv / ROUNDS,
-        f"{S * ROUNDS / us_srv * 1e6:.0f} chunks/s")
 
     # -- async/coalescing front end: G independent callers per round.
-    # sync pays G full feed() calls (dispatch + readback each); async
-    # coalesces the same G submits into shared waves resolved by ONE
-    # drain. Decisions must stay bit-for-bit identical — for BOTH
-    # numerics modes this is a hard gate, not a footnote. --------------------
-    import time as _time
-
+    # sync pays G full feed() calls; async coalesces the same G submits
+    # into shared waves resolved by ONE drain. Decisions must stay
+    # bit-for-bit identical — for BOTH numerics modes. --------------------
     G = 4 if args.smoke else 8
     L_ROUNDS = 2 if args.smoke else 4
     groups = [list(range(g, S, G)) for g in range(G)]
-    # one pipeline + ONE shared compiled step across the fresh servers
-    # below — exactly how the router shares it across shards; without
-    # this, fixed numerics (a per-server jit closure) would recompile in
-    # every pass and the latency rows would measure compile time
+    # one pipeline + ONE shared compiled step across both passes, exactly
+    # how the router shares it across shards
     pipe_c = _pipe(primary_impl)
     step_c = make_batched_step(pipe_c)
 
-    def _caller_pass(async_path: bool):
+    def _caller_pass(async_path: bool) -> dict:
         srv = StreamServer(pipe_c, capacity=S,
                            max_chunk=_pow2_at_least(CH), step_fn=step_c)
         for sid in ids:
             srv.open(sid)
-        lat, dec = [], {}
-        t_all = _time.perf_counter()
+        dec = {}
         for r in range(L_ROUNDS):
             rr = r % ROUNDS
+            parts = [[(ids[i], audio[i, rr * CH:(rr + 1) * CH]) for i in g]
+                     for g in groups]
             if async_path:
-                staged = []
-                for g in groups:
-                    part = [(ids[i], audio[i, rr * CH:(rr + 1) * CH])
-                            for i in g]
-                    staged.append((_time.perf_counter(),
-                                   srv.submit(part)))
+                tickets = [srv.submit(part) for part in parts]
                 srv.drain()
-                t_end = _time.perf_counter()
-                for t0, ticket in staged:
-                    lat.append(t_end - t0)
-                    for res in ticket.results:
-                        dec[(res.session_id, res.samples_seen)] = \
-                            (res.label, res.confidence)
+                results = [res for t in tickets for res in t.results]
             else:
-                for g in groups:
-                    part = [(ids[i], audio[i, rr * CH:(rr + 1) * CH])
-                            for i in g]
-                    t0 = _time.perf_counter()
-                    out = srv.feed(part)
-                    lat.append(_time.perf_counter() - t0)
-                    for res in out:
-                        dec[(res.session_id, res.samples_seen)] = \
-                            (res.label, res.confidence)
-        wall = _time.perf_counter() - t_all
-        return wall, np.asarray(lat) * 1e6, dec
+                results = [res for part in parts for res in srv.feed(part)]
+            for res in results:
+                dec[(res.session_id, res.samples_seen)] = \
+                    (res.label, res.confidence)
+        return dec
 
-    _caller_pass(False)  # warmup (compile off the clock)
-    wall_s, lat_s, dec_s = _caller_pass(False)
-    wall_a, lat_a, dec_a = _caller_pass(True)
-    fed = S * L_ROUNDS
-    row(f"serve_streams.feed_sync_callers{tag}.S{S}.G{G}",
-        wall_s / fed * 1e6, f"{fed / wall_s:.0f} streams/s")
-    row(f"serve_streams.feed_async_coalesced{tag}.S{S}.G{G}",
-        wall_a / fed * 1e6,
-        f"{fed / wall_a:.0f} streams/s "
-        f"speedup_vs_sync={wall_s / wall_a:.2f}x "
-        f"bitwise={dec_s == dec_a}")
-    row(f"serve_streams.feed_latency_sync{tag}.S{S}", None,
-        f"p50={np.percentile(lat_s, 50):.0f}us "
-        f"p99={np.percentile(lat_s, 99):.0f}us")
-    row(f"serve_streams.feed_latency_async{tag}.S{S}", None,
-        f"p50={np.percentile(lat_a, 50):.0f}us "
-        f"p99={np.percentile(lat_a, 99):.0f}us")
-    if dec_s != dec_a:
+    bitwise = _caller_pass(False) == _caller_pass(True)
+    row(f"serve_streams.async_parity{tag}.S{S}.G{G}", None,
+        f"bitwise={bitwise}")
+    if not bitwise:
         raise AssertionError(
             "async/coalesced decisions != sync feed() decisions "
             f"({nm} numerics, {primary_impl}) — the bitwise serving "
@@ -215,21 +123,6 @@ def main(argv=()):
 
     # -- stateful Pallas streaming kernel vs the XLA session step -----------
     if args.stream_impl == "both":
-        pipe_k = _pipe("pallas")
-        server_k = StreamServer(pipe_k, capacity=S,
-                                max_chunk=_pow2_at_least(CH))
-        for sid in ids:
-            server_k.open(sid)
-
-        def served_pallas():
-            res = None
-            for r in range(ROUNDS):
-                res = server_k.feed([(sid, audio[i, r * CH:(r + 1) * CH])
-                                     for i, sid in enumerate(ids)])
-            jax.block_until_ready(server_k.state.acc)
-            return res
-
-        us_k = time_fn(served_pallas, warmup=1, iters=iters)
         # decision parity on FRESH servers (history-free comparison);
         # registers are compared too — the server-parity gate covers the
         # full SessionState, not just the argmax
@@ -254,9 +147,8 @@ def main(argv=()):
             raise AssertionError(
                 "fixed-numerics server parity violated: int Pallas != "
                 "int XLA decisions/registers")
-        row(f"serve_streams.stream_server_pallas{tag}.S{S}xC{CH}", us_k,
-            f"speedup_vs_xla={us_srv / us_k:.2f}x bitwise={bitwise} "
-            f"(interpret mode off-TPU; >=1.5x target is a TPU number)")
+        row(f"serve_streams.pallas_parity{tag}.S{S}xC{CH}", None,
+            f"bitwise={bitwise} (interpret mode off-TPU)")
 
     if nm == "fixed":
         # -- fixed streaming parity: chunked == one-shot at EXACT equality

@@ -73,6 +73,7 @@ __all__ = [
     "fxp_mp_dot",
     "fxp_mpabs",
     "bank_accumulate_q",
+    "cascade_q",
     "standardize_q",
     "classifier_q",
     "infer_q",
@@ -776,9 +777,11 @@ def predict(prog: FixedPointProgram, x, carrier: str = "int", *,
 def readout_q(prog: FixedPointProgram, acc_q):
     """Pure readout from 32-bit accumulator registers: (p_q, phi_q).
     The decision from all evidence so far — what a zero-length session
-    chunk (and every chunk's trailing readout) computes."""
-    phi_q = standardize_q(prog, acc_q)
-    return classifier_q(prog.clf, phi_q), phi_q
+    chunk (and every chunk's trailing readout) computes. Its ops carry
+    the named scope ``readout``."""
+    with jax.named_scope("readout"):
+        phi_q = standardize_q(prog, acc_q)
+        return classifier_q(prog.clf, phi_q), phi_q
 
 
 def session_step_q(prog: FixedPointProgram, state, chunk_q, n):
@@ -808,11 +811,19 @@ def session_step_q(prog: FixedPointProgram, state, chunk_q, n):
     multiplies/divides per chunk); float-carried registers run the
     fake-quant twin bit-identically.
     """
+    state = cascade_q(prog, state, chunk_q, n)
+    p_q, phi_q = readout_q(prog, state.acc)
+    return state, p_q, phi_q
+
+
+def cascade_q(prog: FixedPointProgram, state, chunk_q, n):
+    """The register update of :func:`session_step_q` without its readout:
+    the integer octave cascade over one (S, L) chunk. Returns ``state'``
+    (unchanged for L == 0)."""
     bank = prog.bank
     S, L = chunk_q.shape
     if L == 0:
-        p_q, phi_q = readout_q(prog, state.acc)
-        return state, p_q, phi_q
+        return state
     T1 = state.delays[0].shape[1]
     # running amax telemetry: invalid positions are zero codes, so they
     # never raise the max (|code| >= 0 and the register starts at 0)
@@ -878,7 +889,5 @@ def session_step_q(prog: FixedPointProgram, state, chunk_q, n):
             n_o = jnp.right_shift(jnp.maximum(n_o - start + 1, 0), 1)
             l_max = l_next
     acc = state.acc + jnp.concatenate(parts, axis=-1)
-    state = state._replace(delays=tuple(delays), consumed=tuple(consumed),
-                           acc=acc, amax=amax, count=state.count + n)
-    p_q, phi_q = readout_q(prog, acc)
-    return state, p_q, phi_q
+    return state._replace(delays=tuple(delays), consumed=tuple(consumed),
+                          acc=acc, amax=amax, count=state.count + n)

@@ -375,28 +375,39 @@ class InFilterPipeline:
         lines / accumulators / running amax in VMEM scratch across its
         chunk-block grid. Both run the same solver math in the same blocked
         accumulation order, so in interpret mode they agree bit-for-bit.
+
+        The step's ops carry the named scopes ``session_step`` and, inside
+        it, ``quantize``, ``octave_cascade`` and ``readout`` (in both
+        numerics), which the profiler reports with each device op.
         """
+        with jax.named_scope("session_step"):
+            if self.config.numerics == "fixed":
+                return self._session_step_fixed(state, chunk, valid)
+            return self._session_step_float(state, chunk, valid)
+
+    def _session_step_float(self, state: SessionState, chunk: jax.Array,
+                            valid: jax.Array):
         c = self.config
-        if c.numerics == "fixed":
-            return self._session_step_fixed(state, chunk, valid)
         S, L = chunk.shape
         n = jnp.where(state.active, jnp.asarray(valid, jnp.int32), 0)
-        if L == 0:
-            # a zero-length chunk is a pure readout: no register moves
+        if L > 0:
+            pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
+            chunk = jnp.where(pos0 < n[:, None], chunk, 0)
+            with jax.named_scope("octave_cascade"):
+                if c.stream_impl == "pallas":
+                    state = self._cascade_pallas(state, chunk, n)
+                elif c.stream_impl == "xla":
+                    state = self._cascade_xla(state, chunk, n)
+                else:
+                    # a typo must not silently serve XLA results as "the
+                    # kernel"
+                    raise ValueError(f"unknown stream_impl "
+                                     f"{c.stream_impl!r}: expected 'xla' "
+                                     "or 'pallas'")
+        # a zero-length chunk is a pure readout: no register moves
+        with jax.named_scope("readout"):
             phi = (state.acc - self.mu) / self.sigma
             return state, km.forward(self.clf, phi, exact=False), phi
-        pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
-        chunk = jnp.where(pos0 < n[:, None], chunk, 0)
-        if c.stream_impl == "pallas":
-            state = self._cascade_pallas(state, chunk, n)
-        elif c.stream_impl == "xla":
-            state = self._cascade_xla(state, chunk, n)
-        else:
-            # a typo must not silently serve XLA results as "the kernel"
-            raise ValueError(f"unknown stream_impl {c.stream_impl!r}: "
-                             "expected 'xla' or 'pallas'")
-        phi = (state.acc - self.mu) / self.sigma
-        return state, km.forward(self.clf, phi, exact=False), phi
 
     def _session_step_fixed(self, state: SessionState, chunk: jax.Array,
                             valid: jax.Array):
@@ -404,7 +415,7 @@ class InFilterPipeline:
         grid, zero invalid positions, and run the integer cascade — every
         register stays on the fixed-point grid and chunked decisions are
         bit-for-bit the one-shot program's. The kernel selection happens
-        HERE: "xla" runs ``fixed.session_step_q``; "pallas" runs the
+        HERE: "xla" runs ``fixed.cascade_q``; "pallas" runs the
         VMEM-resident integer kernel (``kernels.fir_mp_stream_q``) —
         bit-identical registers and decisions either way."""
         from repro.core import fixed
@@ -418,16 +429,20 @@ class InFilterPipeline:
         if L == 0:
             xq = jnp.zeros((S, 0), jnp.int32)
         else:
-            xq = fixed.quantize_signal(prog, chunk)
-            pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
-            xq = jnp.where(pos0 < n[:, None], xq, 0)
+            with jax.named_scope("quantize"):
+                xq = fixed.quantize_signal(prog, chunk)
+                pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
+                xq = jnp.where(pos0 < n[:, None], xq, 0)
         if c.stream_impl == "pallas":
             state, p_q, phi_q = self._cascade_pallas_fixed(prog, state,
                                                            xq, n)
         else:
-            state, p_q, phi_q = fixed.session_step_q(prog, state, xq, n)
-        return state, prog.out_spec.dequantize(p_q), \
-            prog.phi.dequantize(phi_q)
+            with jax.named_scope("octave_cascade"):
+                state = fixed.cascade_q(prog, state, xq, n)
+            p_q, phi_q = fixed.readout_q(prog, state.acc)
+        with jax.named_scope("readout"):
+            return state, prog.out_spec.dequantize(p_q), \
+                prog.phi.dequantize(phi_q)
 
     def _cascade_pallas_fixed(self, prog, state: SessionState,
                               xq: jax.Array, n: jax.Array):
@@ -446,11 +461,12 @@ class InFilterPipeline:
             p_q, phi_q = fixed.readout_q(prog, state.acc)
             return state, p_q, phi_q
         from repro.kernels import fir_mp_stream_q
-        delays, consumed, acc, amax = fir_mp_stream_q(
-            prog, xq, n, state.delays, state.consumed, state.acc,
-            state.amax)
-        state = state._replace(delays=delays, consumed=consumed, acc=acc,
-                               amax=amax, count=state.count + n)
+        with jax.named_scope("octave_cascade"):
+            delays, consumed, acc, amax = fir_mp_stream_q(
+                prog, xq, n, state.delays, state.consumed, state.acc,
+                state.amax)
+            state = state._replace(delays=delays, consumed=consumed,
+                                   acc=acc, amax=amax, count=state.count + n)
         p_q, phi_q = fixed.readout_q(prog, acc)
         return state, p_q, phi_q
 
@@ -466,8 +482,10 @@ class InFilterPipeline:
         if c.quant_bits is not None:
             # quantization needs the post-update running amax BEFORE the
             # filter pass, so it cannot fold into the kernel's single sweep
-            amax = jnp.maximum(state.amax, jnp.max(jnp.abs(chunk), axis=-1))
-            chunk = fbm.quant_signal(chunk, c, amax=amax)
+            with jax.named_scope("quantize"):
+                amax = jnp.maximum(state.amax,
+                                   jnp.max(jnp.abs(chunk), axis=-1))
+                chunk = fbm.quant_signal(chunk, c, amax=amax)
             update_amax = False
         else:
             # raw path: the octave-0 kernel folds the running-amax update
@@ -490,7 +508,8 @@ class InFilterPipeline:
         # max over chunks 0..i, converging to the one-shot global scale
         amax = jnp.maximum(state.amax, jnp.max(jnp.abs(chunk), axis=-1))
         if c.quant_bits is not None:
-            chunk = fbm.quant_signal(chunk, c, amax=amax)
+            with jax.named_scope("quantize"):
+                chunk = fbm.quant_signal(chunk, c, amax=amax)
         T1 = self._delay_len
         M_bp, M_lp = c.bp_taps, c.lp_taps
         x_o, n_o = chunk, n

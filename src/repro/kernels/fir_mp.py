@@ -146,6 +146,7 @@ def fir_mp_bank_pallas(
     out = pl.pallas_call(
         functools.partial(_fir_mp_bank_kernel, iters=iters, M=M,
                           accumulate=accumulate, valid_n=N),
+        name="fir_mp_bank",
         grid=(Bp // block_b, F),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
@@ -408,8 +409,10 @@ def fir_mp_stream_octave(
     update_amax: bool = False,
     block_s: int = 8,
     interpret: bool = False,
+    octave: int = 0,
 ):
-    """One octave of the stateful streaming step, as a single pallas_call.
+    """One octave of the stateful streaming step, as a single pallas_call
+    named ``fir_mp_stream_o<octave>``.
 
     x (S, L): this octave's chunk (invalid tails already zeroed/masked
     upstream); n (S,): per-slot valid counts; start (S,): per-slot decimator
@@ -454,6 +457,7 @@ def fir_mp_stream_octave(
         functools.partial(_fir_mp_stream_kernel, solver=solver, scale=scale,
                           emit_next=emit_next, update_amax=update_amax,
                           T1=T1, M=M, M_lp=M_lp, LB=LB),
+        name=f"fir_mp_stream_o{octave}",
         grid=(Sp // bs, NB, F),
         in_specs=[_SMEM, _SMEM, _SMEM] + in_specs,   # gamma, BP taps, LP
         out_specs=out_specs,
@@ -509,6 +513,7 @@ def fir_mp_pallas(
     out = pl.pallas_call(
         functools.partial(_fir_mp_kernel, iters=iters, M=M,
                           accumulate=accumulate, valid_n=N),
+        name="fir_mp",
         grid=(Bp // block_b,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
@@ -651,6 +656,7 @@ def fir_mp_bank_q_pallas(
         functools.partial(_fir_mp_bank_q_kernel, gamma_q=int(gamma_q),
                           iters=int(iters), qmin=int(qmin), qmax=int(qmax),
                           M=M, accumulate=accumulate, valid_n=N),
+        name="fir_mp_bank_q",
         grid=(Bp // block_b, F),
         in_specs=[
             pl.BlockSpec((block_b, Np), lambda i, j: (i, 0)),
@@ -792,8 +798,10 @@ def fir_mp_stream_octave_q(
     update_amax: bool = False,
     block_s: int = 8,
     interpret: bool = False,
+    octave: int = 0,
 ):
-    """One octave of the INTEGER streaming step, as a single pallas_call.
+    """One octave of the INTEGER streaming step, as a single pallas_call
+    named ``fir_mp_stream_q_o<octave>``.
 
     x (S, L): this octave's chunk of register codes (invalid tails already
     zeroed upstream); n (S,): per-slot valid counts; start (S,): per-slot
@@ -849,6 +857,7 @@ def fir_mp_stream_octave_q(
                           next_qmin=next_qmin, next_qmax=next_qmax,
                           emit_next=emit_next, update_amax=update_amax,
                           T1=T1, M=M, M_lp=M_lp, LB=LB),
+        name=f"fir_mp_stream_q_o{octave}",
         grid=(Sp // bs, NB, F),
         in_specs=[_SMEM, _SMEM] + in_specs,          # BP taps, LP taps
         out_specs=out_specs,

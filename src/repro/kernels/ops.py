@@ -235,7 +235,7 @@ def fir_mp_stream(chunk: jax.Array, n: jax.Array, delays: tuple,
             x_o, n_o, start_o, delays[o], acc_o, amax_in, bp_taps[o], lp,
             gamma, scale=2.0 ** o, solver=solver, emit_next=emit,
             update_amax=(update_amax and o == 0), block_s=block_s,
-            interpret=interpret)
+            interpret=interpret, octave=o)
         if o == 0:
             amax_out = amax_new if update_amax else amax
         new_delays.append(delay_new)
@@ -336,7 +336,7 @@ def fir_mp_stream_q(prog, chunk_q: jax.Array, n: jax.Array, delays: tuple,
         acc_new, delay_new, amax_new, y_next = _fir.fir_mp_stream_octave_q(
             x_o, n_o, start_o, delays[o], acc_o, amax_in, stage=st,
             next_spec=next_spec, emit_next=emit, update_amax=(o == 0),
-            block_s=block_s, interpret=interpret)
+            block_s=block_s, interpret=interpret, octave=o)
         if o == 0:
             amax_out = amax_new
         new_delays.append(delay_new)

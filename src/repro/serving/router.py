@@ -30,7 +30,8 @@ import zlib
 from typing import Iterable, List, Optional, Union
 
 from repro.core.pipeline import InFilterPipeline
-from repro.serving.server import StreamServer, make_batched_step
+from repro.serving.server import (COMPILE_SITES, COUNTERS, StreamServer,
+                                  make_batched_step)
 from repro.serving.session import FeedRequest, FeedResult, Session
 
 __all__ = ["StreamRouter", "RouterTicket", "shard_of"]
@@ -147,6 +148,9 @@ class StreamRouter:
             "resident": sum(p["resident"] for p in per),
             "steps_run": sum(p["steps_run"] for p in per),
             "queued_requests": sum(p["queued_requests"] for p in per),
+            **{k: sum(p[k] for p in per) for k in COUNTERS},
+            **{k: {w: sum(p[k][w] for p in per) for w in COMPILE_SITES}
+               for k in ("compiles", "cache_loads")},
             "poisoned": {k: p["poisoned"] for k, p in enumerate(per)
                          if p["poisoned"] is not None} or None,
             "shards": per,

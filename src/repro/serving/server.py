@@ -25,6 +25,14 @@ small submits coalesce into one compiled call per wave instead of one
 full-capacity step each. Decisions are bit-for-bit what the synchronous
 path returns — ``feed()`` IS ``submit()`` + ``drain()``.
 
+Instrumentation: every public call and every stage of a wave records a
+``jax.profiler.TraceAnnotation`` (``serve.*``; a no-op check when no
+profiler trace is active), one per operation and never one per request,
+carrying the wave's step number, bucket or slot as metadata; and
+``stats()`` carries plain counters of the work done (readbacks, staging
+waits, host-to-device bytes, padding, slot resets, compiles inside
+server calls). ``docs/serving.md`` lists both.
+
 Scale-out: pass ``mesh=`` to shard the slot axis over the mesh's data axes
 (see ``repro.distributed.sharding.session_specs``); capacity then scales
 linearly with device count while the host-side API is unchanged. For
@@ -35,18 +43,51 @@ host-side sharding — N servers behind one admission API — see
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from typing import Iterable, List, Optional, Union
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import pipeline as pl
 from repro.core.pipeline import InFilterPipeline, SessionState
 from repro.serving.session import (Decision, FeedRequest, FeedResult,
                                    FeedTicket, Session)
 
-__all__ = ["StreamServer", "bucket_length", "make_batched_step"]
+__all__ = ["StreamServer", "bucket_length", "make_batched_step", "COUNTERS",
+           "COMPILE_SITES"]
+
+# plain work counters of ``StreamServer.stats()`` (the router sums them)
+COUNTERS = ("drains", "readbacks", "stage_waits", "h2d_bytes",
+            "valid_samples", "padded_samples", "slot_resets")
+# where ``stats()["compiles"]`` / ``["cache_loads"]`` happened: the step
+# launch, or the eager state updates of open/close/evict
+COMPILE_SITES = ("launch", "lifecycle")
+
+# Process-wide compile accounting. JAX reports every backend compile,
+# persistent-cache loads included, as one backend-compile event, and a
+# persistent-cache hit as an event of its own inside it; a server charges
+# the change across its own calls to itself.
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_jax_compiles = {"backend": 0, "cache_hits": 0}
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event == _BACKEND_COMPILE:
+        _jax_compiles["backend"] += 1
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT:
+        _jax_compiles["cache_hits"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def bucket_length(n: int, min_chunk: int, max_chunk: int) -> int:
@@ -270,6 +311,9 @@ class StreamServer:
         self._max_history = max_history
         self.bucket_counts: dict[int, int] = {}  # bucket length -> steps run
         self.steps_run = 0
+        self._count = dict.fromkeys(COUNTERS, 0)
+        self._compiles = dict.fromkeys(COMPILE_SITES, 0)
+        self._cache_loads = dict.fromkeys(COMPILE_SITES, 0)
         # set when a donated step call raised mid-feed: the failed call
         # consumed the slot-batched state's buffers, so every resident
         # session's registers are gone — the description names the wave
@@ -283,7 +327,7 @@ class StreamServer:
         self._queue_since: Optional[float] = None
         self._dispatched: List[_Pending] = []  # dispatched, not yet resolved
         # per dispatched wave with at least one finishing request:
-        # (decision device array, [(pending, slot), ...])
+        # (its step number, decision device array, [(pending, slot), ...])
         self._inflight: list = []
 
     # -- introspection -------------------------------------------------------
@@ -335,7 +379,44 @@ class StreamServer:
             "inflight_waves": len(self._inflight),
             "coalesce_watermark": self.coalesce_watermark,
             "coalesce_deadline": self.coalesce_deadline,
+            # work counters (docs/serving.md): drain() calls, blocking
+            # decision readbacks, waits for a staging buffer's last wave,
+            # bytes staged to the device, valid samples staged and the
+            # slots x bucket area they were padded into, state arrays
+            # rewritten eagerly outside the step, and backend compiles /
+            # persistent-cache loads inside this server's calls, by site
+            **self._count,
+            "compiles": dict(self._compiles),
+            "cache_loads": dict(self._cache_loads),
         }
+
+    # -- instrumentation -----------------------------------------------------
+
+    @contextmanager
+    def _compiling(self, site: str):
+        """Charge the backend compiles and persistent-cache loads inside
+        the block to ``site`` (one of ``COMPILE_SITES``)."""
+        c0, h0 = _jax_compiles["backend"], _jax_compiles["cache_hits"]
+        try:
+            yield
+        finally:
+            hits = _jax_compiles["cache_hits"] - h0
+            self._compiles[site] += _jax_compiles["backend"] - c0 - hits
+            self._cache_loads[site] += hits
+
+    @contextmanager
+    def _lifecycle(self, span: str, slot: int):
+        """A lifecycle span around eager state work on ``slot``."""
+        with TraceAnnotation(span, slot=slot), self._compiling("lifecycle"):
+            yield
+
+    def _update_state(self, fn, *args) -> None:
+        """``state = fn(state, *args)`` outside the step, counting each
+        state array it rewrote as one slot reset."""
+        old = jax.tree.leaves(self._state)
+        self._state = fn(self._state, *args)
+        self._count["slot_resets"] += sum(
+            a is not b for a, b in zip(old, jax.tree.leaves(self._state)))
 
     # -- admission -----------------------------------------------------------
 
@@ -346,60 +427,74 @@ class StreamServer:
         numerics modes — an evicted fixed-mode session's integer registers
         round-trip the named-checkpoint store losslessly (dtype-checked),
         so a reopened int32 stream continues bit-for-bit."""
-        self._check_poisoned()
-        # flush the async queue first: admission may evict the LRU session,
-        # and the victim choice / parked registers must reflect every feed
-        # submitted so far (exactly as if they had been synchronous)
-        self._flush_pending()
-        if session_id in self._sessions:
-            raise ValueError(f"session {session_id!r} already open")
-        # validate at admission (checkpoint-name charset), BEFORE any state
-        # changes — a bad id must not cost a slot or surface mid-lifecycle
-        if not session_id or not all(ch.isalnum() or ch in "-_."
-                                     for ch in session_id):
-            raise ValueError(
-                f"session id {session_id!r}: use [A-Za-z0-9._-]")
-        slot = self._acquire_slot()
-        try:
-            now = self._clock()
-            sess = Session(id=session_id, slot=slot, opened_at=now,
-                           last_fed=now, max_history=self._max_history)
-            self._state = pl.clear_slots(self._state, np.asarray([slot]))
-            name = self._ckpt_name(session_id)
-            if self._manager is not None and self._manager.has_named(name):
-                row_like = pl.take_slot(self._state, slot)
-                row, meta = self._manager.restore_named(name, row_like)
-                self._state = pl.put_slot(self._state, slot, row)
-                if meta:
-                    sess.load_meta(meta)
-            self._state = pl.set_active(self._state, np.asarray([slot]),
-                                        True)
-        except Exception:
-            self._free.append(slot)  # failed admission must not leak a slot
-            raise
-        self._sessions[session_id] = sess
-        return sess
+        with TraceAnnotation("serve.open") as span:
+            self._check_poisoned()
+            # flush the async queue first: admission may evict the LRU
+            # session, and the victim choice / parked registers must
+            # reflect every feed submitted so far (exactly as if they had
+            # been synchronous)
+            self._flush_pending()
+            if session_id in self._sessions:
+                raise ValueError(f"session {session_id!r} already open")
+            # validate at admission (checkpoint-name charset), BEFORE any
+            # state changes — a bad id must not cost a slot or surface
+            # mid-lifecycle
+            if not session_id or not all(ch.isalnum() or ch in "-_."
+                                         for ch in session_id):
+                raise ValueError(
+                    f"session id {session_id!r}: use [A-Za-z0-9._-]")
+            slot = self._acquire_slot()
+            span.set_metadata(slot=slot)
+            try:
+                now = self._clock()
+                sess = Session(id=session_id, slot=slot, opened_at=now,
+                               last_fed=now, max_history=self._max_history)
+                with self._lifecycle("serve.slot_reset", slot):
+                    self._update_state(pl.clear_slots, np.asarray([slot]))
+                name = self._ckpt_name(session_id)
+                if self._manager is not None \
+                        and self._manager.has_named(name):
+                    with self._lifecycle("serve.restore", slot):
+                        row_like = pl.take_slot(self._state, slot)
+                        row, meta = self._manager.restore_named(name,
+                                                                row_like)
+                        self._update_state(pl.put_slot, slot, row)
+                    if meta:
+                        sess.load_meta(meta)
+                with self._lifecycle("serve.slot_reset", slot):
+                    self._update_state(pl.set_active, np.asarray([slot]),
+                                       True)
+            except Exception:
+                self._free.append(slot)  # a failed admission keeps no slot
+                raise
+            self._sessions[session_id] = sess
+            return sess
 
     def close(self, session_id: str, *, checkpoint: bool = False) -> Session:
         """Release a session's slot. ``checkpoint=True`` parks its state
         (float or integer registers alike) for a later ``open`` (same as
         eviction); otherwise any parked copy is discarded — a future
         ``open`` of this id starts fresh."""
-        # absorb + resolve any queued feeds for this session before its
-        # registers are parked/discarded — closing must not drop submitted
-        # chunks (the sync path can't, so the async path may not either)
-        self._flush_pending()
-        if session_id not in self._sessions:
-            raise KeyError(f"session {session_id!r} is not open")
-        sess = self._sessions.pop(session_id)
-        if checkpoint:
-            self._park(sess)
-        elif self._manager is not None:
-            self._manager.delete_named(self._ckpt_name(session_id))
-        self._state = pl.set_active(self._state,
-                                    np.asarray([sess.slot]), False)
-        self._free.append(sess.slot)
-        return sess
+        with TraceAnnotation("serve.close") as span:
+            # absorb + resolve any queued feeds for this session before its
+            # registers are parked/discarded — closing must not drop
+            # submitted chunks (the sync path can't, so the async path may
+            # not either)
+            self._flush_pending()
+            if session_id not in self._sessions:
+                raise KeyError(f"session {session_id!r} is not open")
+            sess = self._sessions.pop(session_id)
+            span.set_metadata(slot=sess.slot)
+            if checkpoint:
+                with self._lifecycle("serve.park", sess.slot):
+                    self._park(sess)
+            elif self._manager is not None:
+                self._manager.delete_named(self._ckpt_name(session_id))
+            with self._lifecycle("serve.slot_reset", sess.slot):
+                self._update_state(pl.set_active, np.asarray([sess.slot]),
+                                   False)
+            self._free.append(sess.slot)
+            return sess
 
     def evict(self, session_id: str) -> Session:
         """Park a resident session in the checkpoint store and free its
@@ -501,32 +596,34 @@ class StreamServer:
         ``coalesce_deadline``, or at the latest inside ``drain()``.
         """
         self._check_poisoned()
-        entries = []
-        for r in requests:
-            if isinstance(r, FeedRequest):
-                sid, chunk = r.session_id, r.chunk
-            else:
-                sid, chunk = r
-            if sid not in self._sessions:
-                raise KeyError(f"session {sid!r} is not open")
-            chunk = np.asarray(chunk, dtype=self.dtype)
-            if chunk.ndim != 1:
-                raise ValueError(
-                    f"chunk for {sid!r} must be 1-D (samples,), got shape "
-                    f"{chunk.shape}")
-            if chunk.shape[0] == 0:
-                raise ValueError(f"empty chunk for session {sid!r}")
-            segs = [chunk[i:i + self.max_chunk]
-                    for i in range(0, chunk.shape[0], self.max_chunk)]
-            entries.append((sid, segs, chunk.shape[0]))
-        ticket = FeedTicket(n_requests=len(entries))
-        if not entries:
-            ticket.results = []
-            return ticket
-        for pos, (sid, segs, total) in enumerate(entries):
-            self._queue.append(_Pending(ticket, pos, sid, segs, total))
-        if self._queue_since is None:
-            self._queue_since = self._clock()
+        with TraceAnnotation("serve.submit") as span:
+            entries = []
+            for r in requests:
+                if isinstance(r, FeedRequest):
+                    sid, chunk = r.session_id, r.chunk
+                else:
+                    sid, chunk = r
+                if sid not in self._sessions:
+                    raise KeyError(f"session {sid!r} is not open")
+                chunk = np.asarray(chunk, dtype=self.dtype)
+                if chunk.ndim != 1:
+                    raise ValueError(
+                        f"chunk for {sid!r} must be 1-D (samples,), got "
+                        f"shape {chunk.shape}")
+                if chunk.shape[0] == 0:
+                    raise ValueError(f"empty chunk for session {sid!r}")
+                segs = [chunk[i:i + self.max_chunk]
+                        for i in range(0, chunk.shape[0], self.max_chunk)]
+                entries.append((sid, segs, chunk.shape[0]))
+            span.set_metadata(requests=len(entries))
+            ticket = FeedTicket(n_requests=len(entries))
+            if not entries:
+                ticket.results = []
+                return ticket
+            for pos, (sid, segs, total) in enumerate(entries):
+                self._queue.append(_Pending(ticket, pos, sid, segs, total))
+            if self._queue_since is None:
+                self._queue_since = self._clock()
         if self.coalesce_watermark is not None \
                 and len(self._queue) >= self.coalesce_watermark:
             self._dispatch()
@@ -551,7 +648,7 @@ class StreamServer:
         if self._deadline_expired():
             self._dispatch()
         if self._inflight and all(
-                p.is_ready() for p, _ in self._inflight):
+                p.is_ready() for _, p, _ in self._inflight):
             self._resolve()
         return ticket.results if ticket.done else None
 
@@ -562,6 +659,7 @@ class StreamServer:
         by THIS drain, in submit order. A drained server has no queued
         requests, no unresolved tickets, and no in-flight waves."""
         self._check_poisoned()
+        self._count["drains"] += 1
         self._dispatch()
         return self._resolve()
 
@@ -579,8 +677,9 @@ class StreamServer:
         if self._poisoned is not None:
             return
         if self._queue or self._dispatched or self._inflight:
-            self._dispatch()
-            self._resolve()
+            with TraceAnnotation("serve.flush"):
+                self._dispatch()
+                self._resolve()
 
     def _stage_buffer(self, L: int) -> _StageBuffer:
         """Flip to the next staging buffer for bucket ``L``, waiting (only
@@ -599,7 +698,9 @@ class StreamServer:
             # output being ready proves the input buffer is consumed, so
             # rewriting rows below cannot race the device (and is safe
             # even if the host->device transfer aliased host memory)
-            jax.block_until_ready(buf.inflight)
+            with TraceAnnotation("serve.stage_wait"):
+                jax.block_until_ready(buf.inflight)
+            self._count["stage_waits"] += 1
             buf.inflight = None
         if buf.dirty:
             rows = buf.dirty
@@ -616,64 +717,82 @@ class StreamServer:
         coalesced, bucket = pow2 pad of the wave's longest segment."""
         if not self._queue:
             return
-        reqs, self._queue = self._queue, []
-        self._queue_since = None
-        pending = [list(r.segs) for r in reqs]
-        wave_no = 0
-        while any(pending):
-            wave_no += 1
-            wave, seen, finals = [], set(), []
-            for i, r in enumerate(reqs):
-                if pending[i] and r.sid not in seen:
-                    wave.append((r, pending[i].pop(0)))
-                    seen.add(r.sid)
-                    if not pending[i]:
-                        finals.append(r)
-            L = bucket_length(max(seg.shape[0] for _, seg in wave),
-                              self.min_chunk, self.max_chunk)
+        with TraceAnnotation("serve.dispatch") as span:
+            reqs, self._queue = self._queue, []
+            self._queue_since = None
+            pending = [list(r.segs) for r in reqs]
+            wave_no = 0
+            while any(pending):
+                wave_no += 1
+                self._launch_wave(reqs, pending, wave_no)
+            span.set_metadata(waves=wave_no)
+        self._dispatched.extend(reqs)
+
+    def _launch_wave(self, reqs: list, pending: list, wave_no: int) -> None:
+        """Stage, upload and launch the next wave of ``reqs``: one pending
+        segment per session, ``wave_no`` counting the waves of this
+        dispatch."""
+        wave, seen, finals = [], set(), []
+        for i, r in enumerate(reqs):
+            if pending[i] and r.sid not in seen:
+                wave.append((r, pending[i].pop(0)))
+                seen.add(r.sid)
+                if not pending[i]:
+                    finals.append(r)
+        L = bucket_length(max(seg.shape[0] for _, seg in wave),
+                          self.min_chunk, self.max_chunk)
+        step_no = self.steps_run           # the wave's id in every span
+        with TraceAnnotation("serve.stage", wave=step_no, bucket=L):
             buf = self._stage_buffer(L)
             for r, seg in wave:
                 slot = self._sessions[r.sid].slot
                 buf.batch[slot, :seg.shape[0]] = seg
                 buf.valid[slot] = seg.shape[0]
                 buf.dirty.append(slot)
+        nbytes = buf.batch.nbytes + buf.valid.nbytes
+        with TraceAnnotation("serve.h2d", wave=step_no, bytes=nbytes):
             chunk_dev = jnp.asarray(buf.batch)
             valid_dev = jnp.asarray(buf.valid)
             if self._chunk_sharding is not None:
                 chunk_dev = jax.device_put(chunk_dev, self._chunk_sharding)
                 valid_dev = jax.device_put(valid_dev, self._valid_sharding)
-            # the step donates self._state: if the call raises, the old
-            # buffers are already consumed and there is no state to roll
-            # back to — mid-multi-wave the earlier waves are absorbed and
-            # the rest never ran, so no resident register set is
-            # trustworthy. Poison the server (feed/open fail loudly from
-            # here on, naming this wave) rather than limping on with a
-            # half-stepped or invalidated state.
-            try:
+        self._count["h2d_bytes"] += nbytes
+        self._count["valid_samples"] += sum(seg.shape[0] for _, seg in wave)
+        self._count["padded_samples"] += buf.batch.size
+        # the step donates self._state: if the call raises, the old
+        # buffers are already consumed and there is no state to roll
+        # back to — mid-multi-wave the earlier waves are absorbed and
+        # the rest never ran, so no resident register set is
+        # trustworthy. Poison the server (feed/open fail loudly from
+        # here on, naming this wave) rather than limping on with a
+        # half-stepped or invalidated state.
+        try:
+            with TraceAnnotation("serve.launch", wave=step_no), \
+                    self._compiling("launch"):
                 self._state, p = self._step(self.pipeline, self._state,
                                             chunk_dev, valid_dev)
-            except Exception as e:
-                self._poisoned = (
-                    f"step raised {type(e).__name__} on wave {wave_no} of "
-                    f"a feed() call (bucket {L}, sessions "
-                    f"{sorted(r.sid for r, _ in wave)})")
-                raise RuntimeError(
-                    f"feed() failed: {self._poisoned}; the donated session "
-                    "state was consumed by the failed call — the server "
-                    "is now poisoned") from e
-            self.steps_run += 1
-            self.bucket_counts[L] = self.bucket_counts.get(L, 0) + 1
-            # NO host readback here: the decision array rides along
-            # asynchronously and gates this buffer's reuse; requests
-            # finishing on this wave are read back (vectorized) at the
-            # next drain point. Slots are captured now — resolution may
-            # happen after this session moved (it cannot close first:
-            # close() flushes).
-            buf.inflight = p
-            if finals:
-                self._inflight.append(
-                    (p, [(r, self._sessions[r.sid].slot) for r in finals]))
-        self._dispatched.extend(reqs)
+        except Exception as e:
+            self._poisoned = (
+                f"step raised {type(e).__name__} on wave {wave_no} of "
+                f"a feed() call (bucket {L}, sessions "
+                f"{sorted(r.sid for r, _ in wave)})")
+            raise RuntimeError(
+                f"feed() failed: {self._poisoned}; the donated session "
+                "state was consumed by the failed call — the server "
+                "is now poisoned") from e
+        self.steps_run += 1
+        self.bucket_counts[L] = self.bucket_counts.get(L, 0) + 1
+        # NO host readback here: the decision array rides along
+        # asynchronously and gates this buffer's reuse; requests
+        # finishing on this wave are read back (vectorized) at the
+        # next drain point. Slots are captured now — resolution may
+        # happen after this session moved (it cannot close first:
+        # close() flushes).
+        buf.inflight = p
+        if finals:
+            self._inflight.append(
+                (step_no, p,
+                 [(r, self._sessions[r.sid].slot) for r in finals]))
 
     def _resolve(self) -> list:
         """Materialize every dispatched request's decision (ONE blocking
@@ -683,34 +802,37 @@ class StreamServer:
         decision rows, same samples_seen bookkeeping order."""
         if not self._dispatched:
             return []
-        for p_dev, finals in self._inflight:
-            p_host = np.asarray(p_dev)          # blocks if not yet ready
-            slots = np.asarray([s for _, s in finals])
-            rows = p_host[slots]
-            labels = np.argmax(rows, axis=1)
-            for (r, _), label, row in zip(finals, labels, rows):
-                r.label = int(label)
-                r.conf = float(row[label])
-        self._inflight.clear()
-        now = self._clock()
-        results = []
-        tickets = []
-        for r in self._dispatched:
-            sess = self._sessions[r.sid]
-            # samples_seen advances by the WHOLE request, recorded once on
-            # its final segment's decision
-            total = sess.samples_seen + r.total
-            d = Decision(samples_seen=total, label=r.label,
-                         confidence=r.conf)
-            sess.record(d, now)
-            fr = FeedResult(session_id=r.sid, label=r.label, confidence=r.conf,
-                            samples_seen=total)
-            results.append(fr)
-            if r.ticket.results is None:
-                r.ticket.results = [None] * r.ticket.n_requests
-                tickets.append(r.ticket)
-            r.ticket.results[r.pos] = fr
-        self._dispatched.clear()
+        with TraceAnnotation("serve.resolve"):
+            for wave, p_dev, finals in self._inflight:
+                with TraceAnnotation("serve.readback", wave=wave):
+                    p_host = np.asarray(p_dev)  # blocks if not yet ready
+                self._count["readbacks"] += 1
+                slots = np.asarray([s for _, s in finals])
+                rows = p_host[slots]
+                labels = np.argmax(rows, axis=1)
+                for (r, _), label, row in zip(finals, labels, rows):
+                    r.label = int(label)
+                    r.conf = float(row[label])
+            self._inflight.clear()
+            now = self._clock()
+            results = []
+            tickets = []
+            for r in self._dispatched:
+                sess = self._sessions[r.sid]
+                # samples_seen advances by the WHOLE request, recorded once
+                # on its final segment's decision
+                total = sess.samples_seen + r.total
+                d = Decision(samples_seen=total, label=r.label,
+                             confidence=r.conf)
+                sess.record(d, now)
+                fr = FeedResult(session_id=r.sid, label=r.label,
+                                confidence=r.conf, samples_seen=total)
+                results.append(fr)
+                if r.ticket.results is None:
+                    r.ticket.results = [None] * r.ticket.n_requests
+                    tickets.append(r.ticket)
+                r.ticket.results[r.pos] = fr
+            self._dispatched.clear()
         # a ticket is dispatched atomically (dispatch flushes the whole
         # queue), so every ticket touched here resolved completely
         assert all(None not in t.results for t in tickets)

@@ -134,8 +134,15 @@ def _lower_step(pipe, sharding, mesh=None):
 def test_session_step_compiles(one_chip, compiled_kernels, numerics, impl):
     pipe = make_pipeline(smoke=False, stream_impl=impl, numerics=numerics,
                          fixed_amax=4.0 if numerics == "fixed" else None)
-    c = _lower_step(pipe, one_chip)
-    assert ("tpu_custom_call" in c.as_text()) == (impl == "pallas")
+    text = _lower_step(pipe, one_chip).as_text()
+    assert ("tpu_custom_call" in text) == (impl == "pallas")
+    # the profiler names each octave's kernel, and reports the step's
+    # named scopes with every op
+    kernel = "fir_mp_stream_q" if numerics == "fixed" else "fir_mp_stream"
+    for o in range(FILTERBANK.num_octaves):
+        assert (f"%{kernel}_o{o}." in text) == (impl == "pallas")
+    for scope in ("octave_cascade", "readout"):
+        assert f"/session_step/{scope}/" in text
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
