@@ -85,6 +85,7 @@ __all__ = [
     "StreamingState",
     "clear_slots",
     "set_active",
+    "reset_slot",
     "take_slot",
     "put_slot",
 ]
@@ -658,10 +659,20 @@ def clear_slots(state: SessionState, slots) -> SessionState:
     )
 
 
-def set_active(state: SessionState, slots, value: bool) -> SessionState:
-    """Flip the admission mask for ``slots``."""
+def set_active(state: SessionState, slots, value) -> SessionState:
+    """Flip the admission mask for ``slots`` (``value`` may be traced)."""
     return state._replace(
-        active=state.active.at[jnp.asarray(slots)].set(bool(value)))
+        active=state.active.at[jnp.asarray(slots)].set(
+            jnp.asarray(value, bool)))
+
+
+def reset_slot(state: SessionState, slot, active) -> SessionState:
+    """Zero one slot's registers and set its admission flag:
+    :func:`clear_slots` then :func:`set_active`. ``slot`` and ``active``
+    may be traced, so one jitted program serves every slot, admission
+    (``active`` True) and release (False) alike."""
+    slots = jnp.reshape(jnp.asarray(slot), (1,))
+    return set_active(clear_slots(state, slots), slots, active)
 
 
 def take_slot(state: SessionState, slot: int) -> SessionState:

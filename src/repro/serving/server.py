@@ -42,6 +42,7 @@ host-side sharding — N servers behind one admission API — see
 
 from __future__ import annotations
 
+import functools
 import time
 from contextlib import contextmanager
 from typing import Iterable, List, Optional, Union
@@ -64,8 +65,19 @@ __all__ = ["StreamServer", "bucket_length", "make_batched_step", "COUNTERS",
 COUNTERS = ("drains", "readbacks", "stage_waits", "h2d_bytes",
             "valid_samples", "padded_samples", "slot_resets")
 # where ``stats()["compiles"]`` / ``["cache_loads"]`` happened: the step
-# launch, or the eager state updates of open/close/evict
+# launch, or the slot updates of open/close/evict
 COMPILE_SITES = ("launch", "lifecycle")
+
+
+@functools.cache
+def _reset_program(layout=None):
+    """The lifecycle program: ``pl.reset_slot`` on the donated state. The
+    slot and the flag are traced, so one executable serves every slot, open
+    and close alike. Under a mesh, ``layout`` is the state leaves'
+    shardings, which the outputs keep: the next step neither reshards nor
+    recompiles."""
+    kw = {} if layout is None else {"out_shardings": layout}
+    return jax.jit(pl.reset_slot, donate_argnums=(0,), **kw)
 
 # Process-wide compile accounting. JAX reports every backend compile,
 # persistent-cache loads included, as one backend-compile event, and a
@@ -382,8 +394,8 @@ class StreamServer:
             # work counters (docs/serving.md): drain() calls, blocking
             # decision readbacks, waits for a staging buffer's last wave,
             # bytes staged to the device, valid samples staged and the
-            # slots x bucket area they were padded into, state arrays
-            # rewritten eagerly outside the step, and backend compiles /
+            # slots x bucket area they were padded into, lifecycle
+            # programs launched outside the step, and backend compiles /
             # persistent-cache loads inside this server's calls, by site
             **self._count,
             "compiles": dict(self._compiles),
@@ -406,17 +418,17 @@ class StreamServer:
 
     @contextmanager
     def _lifecycle(self, span: str, slot: int):
-        """A lifecycle span around eager state work on ``slot``."""
+        """A lifecycle span around state work on ``slot``."""
         with TraceAnnotation(span, slot=slot), self._compiling("lifecycle"):
             yield
 
-    def _update_state(self, fn, *args) -> None:
-        """``state = fn(state, *args)`` outside the step, counting each
-        state array it rewrote as one slot reset."""
-        old = jax.tree.leaves(self._state)
-        self._state = fn(self._state, *args)
-        self._count["slot_resets"] += sum(
-            a is not b for a, b in zip(old, jax.tree.leaves(self._state)))
+    def _reset_slot(self, slot: int, active: bool) -> None:
+        """Zero ``slot``'s registers and write its admission flag: one
+        launch of the donated lifecycle program, counted as a slot reset."""
+        reset = _reset_program() if self._mesh is None else _reset_program(
+            jax.tree.map(lambda a: a.sharding, self._state))
+        self._state = reset(self._state, np.int32(slot), np.bool_(active))
+        self._count["slot_resets"] += 1
 
     # -- admission -----------------------------------------------------------
 
@@ -449,21 +461,23 @@ class StreamServer:
                 now = self._clock()
                 sess = Session(id=session_id, slot=slot, opened_at=now,
                                last_fed=now, max_history=self._max_history)
-                with self._lifecycle("serve.slot_reset", slot):
-                    self._update_state(pl.clear_slots, np.asarray([slot]))
                 name = self._ckpt_name(session_id)
-                if self._manager is not None \
-                        and self._manager.has_named(name):
+                restore = self._manager is not None \
+                    and self._manager.has_named(name)
+                # a restored slot is admitted by its row's write, so a
+                # restore that fails leaves the slot cleared and inactive
+                with self._lifecycle("serve.slot_reset", slot):
+                    self._reset_slot(slot, not restore)
+                if restore:
                     with self._lifecycle("serve.restore", slot):
                         row_like = pl.take_slot(self._state, slot)
                         row, meta = self._manager.restore_named(name,
                                                                 row_like)
-                        self._update_state(pl.put_slot, slot, row)
+                        self._state = pl.put_slot(
+                            self._state, slot, row._replace(active=True))
+                        self._count["slot_resets"] += 1
                     if meta:
                         sess.load_meta(meta)
-                with self._lifecycle("serve.slot_reset", slot):
-                    self._update_state(pl.set_active, np.asarray([slot]),
-                                       True)
             except Exception:
                 self._free.append(slot)  # a failed admission keeps no slot
                 raise
@@ -490,9 +504,10 @@ class StreamServer:
                     self._park(sess)
             elif self._manager is not None:
                 self._manager.delete_named(self._ckpt_name(session_id))
+            # the registers are parked or discarded by now, and no step
+            # reads an inactive slot: clearing them with the flag is free
             with self._lifecycle("serve.slot_reset", sess.slot):
-                self._update_state(pl.set_active, np.asarray([sess.slot]),
-                                   False)
+                self._reset_slot(sess.slot, False)
             self._free.append(sess.slot)
             return sess
 

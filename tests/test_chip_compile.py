@@ -3,8 +3,9 @@
 The TPU compiler is installed with jaxlib and compiles for a topology that
 is described rather than attached. These tests compile, at the paper's
 full widths, what ``chip_smoke.py`` runs on the chip: both streaming
-kernels, the batched session step in both numerics under both impls, and
-the slot-sharded fixed step on a four-chip mesh. Mosaic refuses shapes and
+kernels, the batched session step in both numerics under both impls, the
+slot-sharded fixed step on a four-chip mesh, and the slot reset of
+``open()`` and ``close()``. Mosaic refuses shapes and
 ops that interpret mode accepts (unaligned blocks, lane gathers, strided
 lane slices), so these catch on the CPU what would otherwise fail on the
 chip. Nothing runs: a pass says the programs compile, not what they
@@ -160,6 +161,32 @@ def test_slot_sharded_fixed_step_is_collective_free(topo, compiled_kernels,
     assert ("tpu_custom_call" in text) == (impl == "pallas")
     for collective in ("all-gather", "all-reduce", "all-to-all"):
         assert collective not in text
+
+
+@pytest.mark.parametrize("numerics", ["float", "fixed"])
+def test_lifecycle_program_compiles(topo, numerics):
+    """The donated slot reset of open() and close() compiles at full width
+    with the slot and the flag traced, on one chip and on a 4-way slot mesh,
+    where every leaf comes out with the sharding it went in with."""
+    from repro.distributed.sharding import session_shardings
+    from repro.serving.server import _reset_program
+    pipe = make_pipeline(smoke=False, numerics=numerics,
+                         fixed_amax=4.0 if numerics == "fixed" else None)
+    state = pipe.init_session(S, active=np.zeros((S,), bool))
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    for program, layout, scalar in (
+            (_reset_program(), jax.tree.map(lambda a: one_chip, state),
+             one_chip),
+            (_reset_program(session_shardings(state, mesh)),
+             session_shardings(state, mesh), NamedSharding(mesh, P()))):
+        c = program.lower(
+            jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), state,
+                         layout),
+            _sds((), jnp.int32, scalar), _sds((), jnp.bool_, scalar)
+        ).compile()
+        assert jax.tree.leaves(c.output_shardings) == jax.tree.leaves(layout)
 
 
 def test_stream_shapes_table_is_tile_aligned():

@@ -68,10 +68,10 @@ def _scenario(srv: StreamServer) -> None:
 
 # per wave of the scenario: (bucket, valid samples)
 WAVES = [(64, 10 + 40 + 64), (32, 20), (16, 32), (16, 16), (16, 16)]
-# state arrays rewritten outside the step: clear_slots rewrites the delay
-# lines and sample counts of every octave plus acc, amax and count;
-# set_active the active mask; a restore every array of the slot's row
-CLEAR, ACTIVE, RESTORE = 2 * OCTAVES + 3, 1, 2 * OCTAVES + 4
+# lifecycle programs launched outside the step: one slot reset per open
+# (a, b, c, d, e and d's reopen) and per close (a, and d's checkpointing
+# close), and one row write per restore (d's reopen)
+OPENS, CLOSES, RESTORES = 6, 2, 1
 EXPECTED = {
     "drains": 3,                           # drain() and two feed()s
     "readbacks": 5,                        # every wave carries a final
@@ -79,7 +79,7 @@ EXPECTED = {
     "h2d_bytes": sum(S * L * 4 + S * 4 for L, _ in WAVES),
     "valid_samples": sum(v for _, v in WAVES),
     "padded_samples": sum(S * L for L, _ in WAVES),
-    "slot_resets": 6 * (CLEAR + ACTIVE) + 2 * ACTIVE + RESTORE,
+    "slot_resets": OPENS + CLOSES + RESTORES,
 }
 
 
