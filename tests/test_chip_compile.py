@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with jaxlib and compiles for a topology that
 is described rather than attached. These tests compile, at the paper's
-full widths, what ``chip_smoke.py`` runs on the chip: both streaming
-kernels, the batched session step in both numerics under both impls, the
+full widths, what ``chip_smoke.py`` and the benchmark run on the chip:
+both streaming kernels, the batched session step in both numerics under
+both impls (and at the backlog cells' 4096-sample wave), the
 slot-sharded fixed step on a four-chip mesh, and the slot reset of
 ``open()`` and ``close()``. Mosaic refuses shapes and
 ops that interpret mode accepts (unaligned blocks, lane gathers, strided
@@ -36,6 +37,7 @@ fir_mp = importlib.import_module("repro.kernels.fir_mp")
 
 S = 256                # session capacity served by chip_smoke.py
 BUCKET = 256           # a 160-sample packet pads to this pow2 bucket
+WAVE = 4096            # a backlog request: one 4096-sample chunk, one wave
 
 
 @pytest.fixture(scope="module")
@@ -119,23 +121,29 @@ def test_int_stream_kernel_compiles(one_chip, chunk):
     assert "tpu_custom_call" in c.as_text()
 
 
-def _lower_step(pipe, sharding, mesh=None):
+def _lower_step(pipe, sharding, mesh=None, bucket=BUCKET):
     from repro.serving.server import make_batched_step
     state = pipe.init_session(S, active=np.ones((S,), bool))
     like = lambda a: _sds(a.shape, a.dtype, sharding)
     step = make_batched_step(pipe, mesh)
     return step.lower(
         jax.tree.map(like, pipe) if pipe.config.numerics == "float" else pipe,
-        jax.tree.map(like, state), _sds((S, BUCKET), jnp.float32, sharding),
+        jax.tree.map(like, state), _sds((S, bucket), jnp.float32, sharding),
         _sds((S,), jnp.int32, sharding)).compile()
 
 
-@pytest.mark.parametrize("numerics", ["float", "fixed"])
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_session_step_compiles(one_chip, compiled_kernels, numerics, impl):
+@pytest.mark.parametrize("impl,numerics,bucket", [
+    pytest.param(impl, numerics, BUCKET, id=f"{impl}-{numerics}")
+    for numerics in ("float", "fixed") for impl in ("xla", "pallas")] + [
+    pytest.param("pallas", numerics, WAVE, id=f"pallas-{numerics}-{WAVE}")
+    for numerics in ("float", "fixed")])
+def test_session_step_compiles(one_chip, compiled_kernels, numerics, impl,
+                               bucket):
+    """At a live packet's bucket in both impls, and at a backlog wave in
+    the Pallas step that the benchmark's backlog cells time."""
     pipe = make_pipeline(smoke=False, stream_impl=impl, numerics=numerics,
                          fixed_amax=4.0 if numerics == "fixed" else None)
-    text = _lower_step(pipe, one_chip).as_text()
+    text = _lower_step(pipe, one_chip, bucket=bucket).as_text()
     assert ("tpu_custom_call" in text) == (impl == "pallas")
     # the profiler names each octave's kernel, and reports the step's
     # named scopes with every op
