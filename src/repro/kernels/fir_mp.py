@@ -550,9 +550,11 @@ def fir_mp_pallas(
 # exists only because float addition is not associative).
 
 
-def _fxp_mpabs_ops(ops, gamma_q, iters: int):
+def _fxp_mpabs_ops(ops, gamma_q, iters: int, ref=None):
     """fixed.fxp_mpabs over an unrolled operand list (each (bb, N)): the
-    per-position integer bisection, shift/add/compare only."""
+    per-position integer bisection, shift/add/compare only. Given ``ref``,
+    a (K, bb, N) VMEM ref that holds ``ops``, the loop body loads them from
+    it, aligned, instead of carrying the values into the loop."""
     g = fx._c(gamma_q, ops[0])
     hi = jnp.abs(ops[0])
     for t in ops[1:]:
@@ -562,9 +564,12 @@ def _fxp_mpabs_ops(ops, gamma_q, iters: int):
     def body(_, state):
         lo, hi = state
         mid = fx.shift_right(lo + hi, 1)
+        neg_mid = -mid                     # -t - mid == neg_mid - t, exactly
+        loaded = None if ref is None else ref[...]
         h = jnp.zeros_like(mid)
-        for t in ops:
-            h = h + fx._relu(t - mid) + fx._relu(-t - mid)
+        for k in range(len(ops)):
+            t = ops[k] if ref is None else loaded[k]
+            h = h + fx._relu(t - mid) + fx._relu(neg_mid - t)
         too_low = h > g
         lo = jnp.where(too_low, mid, lo)
         hi = jnp.where(too_low, hi, mid)
@@ -672,15 +677,24 @@ def fir_mp_bank_q_pallas(
     return out[:, :B, :N]
 
 
-def _fxp_mp_dot_ops(xs, ws, *, gamma_q, iters, spec):
+def _fxp_mp_dot_ops(xs, ws, *, gamma_q, iters, spec, into=None):
     """``fixed.fxp_mp_dot`` over unrolled operands (``xs[k]`` the k-th
     window column, ``ws[k]`` its tap): operand sums saturate onto ``spec``,
     then the two integer bisections. Integer max and adds are
-    order-free, so this is bit-for-bit the array form."""
+    order-free, so this is bit-for-bit the array form.
+
+    ``into`` = (u_ref, v_ref), two (K, bb, N) VMEM refs: the saturated
+    operands are stored there and each bisection loop loads its own, so
+    no operand is carried (and realigned) through a loop."""
     us = [jnp.clip(w + x, spec.qmin, spec.qmax) for x, w in zip(xs, ws)]
     vs = [jnp.clip(w - x, spec.qmin, spec.qmax) for x, w in zip(xs, ws)]
-    return (_fxp_mpabs_ops(us, gamma_q, iters)
-            - _fxp_mpabs_ops(vs, gamma_q, iters))
+    u_ref = v_ref = None
+    if into is not None:
+        u_ref, v_ref = into
+        u_ref[...] = jnp.stack(us)
+        v_ref[...] = jnp.stack(vs)
+    return (_fxp_mpabs_ops(us, gamma_q, iters, u_ref)
+            - _fxp_mpabs_ops(vs, gamma_q, iters, v_ref))
 
 
 def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
@@ -695,10 +709,11 @@ def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
     the chunk_block axis — but every op is the fixed-point datapath:
 
     * window codes rescale onto the band grid by ``stage.sig_shift``
-      (a static shift), operand sums clamp onto the 10-bit internal specs,
-      and each position solves by integer bisection
-      (``fixed.fxp_mp_dot``) — LSB-deterministic, so no float-style
-      reduction-order bookkeeping is needed anywhere;
+      (a static shift), once a block, into scratch that the block's filter
+      steps share; operand sums clamp onto the 10-bit internal specs, and
+      each position solves by integer bisection (``fixed.fxp_mp_dot``) —
+      LSB-deterministic, so no float-style reduction-order bookkeeping is
+      needed anywhere;
     * the flush applies ``stage.acc_shift`` as a left shift (the int mirror
       of the float kernel's ``* 2**octave`` renorm — shifts distribute over
       the partial sums, so flush-time shifting equals the XLA session
@@ -713,10 +728,10 @@ def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
     """
     if emit_next:
         out_acc_ref, out_delay_ref, out_amax_ref, out_next_ref = refs[:4]
-        delay_s, part_s, amax_s = refs[4:]
+        delay_s, part_s, amax_s, win_s, u_s, v_s = refs[4:]
     else:
         out_acc_ref, out_delay_ref, out_amax_ref = refs[:3]
-        delay_s, part_s, amax_s = refs[3:]
+        delay_s, part_s, amax_s, win_s, u_s, v_s = refs[3:]
 
     b = pl.program_id(1)
     f = pl.program_id(2)
@@ -744,12 +759,21 @@ def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
                 jnp.max(jnp.abs(blk), axis=-1, keepdims=True))
 
     # --- band-pass filter f over this block (integer MP solve) ------------
-    bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
-    win = [fx.rescale(w, stage.sig_shift)            # onto the band grid
-           for w in _fir_windows(bufv, M, LB)]
+    # The M tap windows are unaligned lane slices of [delay tail, blk] and
+    # do not depend on the filter: build them once a block into scratch,
+    # lane-aligned. The solve reads them aligned and stores its operands
+    # for the loops to load, so no lane rotate runs inside either loop.
+    @pl.when(f == 0)
+    def _windows():
+        bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
+        win_s[...] = jnp.stack([fx.rescale(w, stage.sig_shift)
+                                for w in _fir_windows(bufv, M, LB)])
+
+    win = win_s[...]
     taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
-    y = _fxp_mp_dot_ops(win, taps, gamma_q=stage.gamma_bp,
-                        iters=stage.iters_bp, spec=stage.band_spec)
+    y = _fxp_mp_dot_ops([win[k] for k in range(M)], taps,
+                        gamma_q=stage.gamma_bp, iters=stage.iters_bp,
+                        spec=stage.band_spec, into=(u_s, v_s))
     pos = b * LB + jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
     hwr = jnp.where(pos < nv, fx._relu(y), 0)
     part_s[...] = _add_column(part_s[...], f,
@@ -866,6 +890,9 @@ def fir_mp_stream_octave_q(
             pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
             pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
             pltpu.VMEM((bs, 1), dt),     # running amax
+            pltpu.VMEM((M, bs, LB), dt),  # tap windows, carried across filters
+            pltpu.VMEM((M, bs, LB), dt),  # band-pass operands h + x
+            pltpu.VMEM((M, bs, LB), dt),  # band-pass operands h - x
         ],
         compiler_params=_SEQUENTIAL,
         interpret=interpret,

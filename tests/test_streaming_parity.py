@@ -526,6 +526,37 @@ def test_fixed_pallas_slot_lifecycles_bitwise(seed):
                                       err_msg=f"seed={seed} slot={s}")
 
 
+def test_fixed_pallas_multi_block_chunk_bitwise():
+    """A 1,100-sample chunk spans three 512-sample blocks at octave 0 and
+    two at octave 1, so the int kernel rebuilds its window scratch per
+    block and reuses it across the filters of each. Slot 0's valid count
+    ends mid-block, and its decimator phases are odd, even, odd (3 samples
+    of silence consumed); slot 1 has 0 valid samples and even phases.
+    Every register equals ``fixed.session_step_q``'s."""
+    from repro.core import fixed
+    from repro.kernels import fir_mp_stream_q
+
+    pipe, _ = _fixed_pipe(stream_impl="pallas")
+    prog = pipe.fixed_program()
+    st = pipe.init_session(2)
+    st = st._replace(consumed=tuple(
+        c.at[0].set(k) for c, k in zip(st.consumed, (3, 2, 1))))
+    n = jnp.asarray([777, 0], jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(16), (2, 1100))
+    pos = jnp.arange(x.shape[1])[None, :]
+    xq = jnp.where(pos < n[:, None], fixed.quantize_signal(prog, x), 0)
+    got = jax.jit(lambda q, s: fir_mp_stream_q(
+        prog, q, n, s.delays, s.consumed, s.acc, s.amax))(xq, st)
+    want = jax.jit(lambda q, s: fixed.session_step_q(prog, s, q, n)[0])(
+        xq, st)
+    for name, a, b in zip(("delays", "consumed", "acc", "amax"), got,
+                          (want.delays, want.consumed, want.acc,
+                           want.amax)):
+        for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
+                                          err_msg=f"{name} diverged")
+
+
 def test_fixed_stream_server_end_to_end(tmp_path):
     """StreamServer serves numerics='fixed': open/feed/split/evict/reopen,
     with the final decision per stream equal (exactly — same codes, same
