@@ -255,9 +255,7 @@ def main(argv=()):
     state = pipe.init_session(1)
     xq = fixed.quantize_signal(prog, jnp.zeros((1, chunk_len)))
     nv = jnp.full((1,), chunk_len, jnp.int32)
-    c, prog_ir = census_ir(
-        lambda st, q, v: pipe._cascade_pallas_fixed(prog, st, q, v),
-        state, xq, nv, tag=tag)
+    c, prog_ir = census_ir(pipe._session_step_q, state, xq, nv, tag=tag)
     assert_multiplierless(c, tag)
     emit_rows(tag, c, chunk_len)
     emit_alloc_rows(tag, prog_ir)

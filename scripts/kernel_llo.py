@@ -1,14 +1,14 @@
 #!/usr/bin/env python3
 """Count the LLO ops Mosaic emits for octave 0's streaming kernel, per loop.
 
-Compiles octave 0 of ``fir_mp_stream_octave_q`` (``--numerics fixed``) or
-``fir_mp_stream_octave`` (``float``) of the full-width esc10-mp pipeline
-for a described TPU v5e, with no chip, at S slots x an L-sample chunk and
-``block_s`` 8, with Mosaic's dump on. Prints the ops of every ``scf.for``
-body (the MP solves' loops: one iteration each) and of the code outside
-them, with the lane rotates (``llo.vrot.lane``), selects and vector loads
-among them. Ops are counted before register allocation: a count, not a
-time.
+Compiles octave 0 of ``fir_mp_stream_octave`` on its integer datapath
+(``--numerics fixed``) or its float one (``float``) of the full-width
+esc10-mp pipeline for a described TPU v5e, with no chip, at S slots x an
+L-sample chunk and ``block_s`` 8, with Mosaic's dump on. Prints the ops
+of every ``scf.for`` body (the MP solves' loops: one iteration each) and
+of the code outside them, with the lane rotates (``llo.vrot.lane``),
+selects and vector loads among them. Ops are counted before register
+allocation: a count, not a time.
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python scripts/kernel_llo.py \\
         [--numerics fixed|float] [--slots 256] [--chunk 4096] [--dump DIR]
@@ -75,16 +75,22 @@ def compile_octave0(numerics: str, S: int, L: int) -> str:
         prog = make_pipeline(smoke=False, numerics="fixed",
                              fixed_amax=4.0).fixed_program()
         dt = jnp.int32
+        path = fir_mp.FixedPath(prog.bank.octaves[0],
+                                prog.bank.octaves[1].in_spec)
 
         def octave(x, n, start, delay, acc, amax):
-            return fir_mp.fir_mp_stream_octave_q(
-                x, n, start, delay, acc, amax, stage=prog.bank.octaves[0],
-                next_spec=prog.bank.octaves[1].in_spec, update_amax=True)
+            return fir_mp.fir_mp_stream_octave(x, n, start, delay, acc, amax,
+                                               path, update_amax=True)
         extra = []
         name = "fir_mp_stream_q_o0"
     else:
         dt = jnp.float32
-        octave = lambda *a: fir_mp.fir_mp_stream_octave(*a, update_amax=True)
+
+        def octave(x, n, start, delay, acc, amax, H, lp, gamma):
+            return fir_mp.fir_mp_stream_octave(
+                x, n, start, delay, acc, amax,
+                fir_mp.FloatPath(H, lp, gamma),
+                update_amax=True)
         extra = [((F, M), dt), ((FILTERBANK.lp_taps,), dt), ((), dt)]
         name = "fir_mp_stream_o0"
     shapes = [((S, L), dt), ((S,), jnp.int32), ((S,), jnp.int32),

@@ -187,9 +187,7 @@ def build_targets(smoke: bool = False) -> tuple:
     pipe_pl = _fixed_pipeline(smoke, stream_impl="pallas")
     prog_pl = pipe_pl.fixed_program()
     state_pl = pipe_pl.init_session(1)
-    jaxpr_spl = jax.make_jaxpr(
-        lambda st, q, v: pipe_pl._cascade_pallas_fixed(prog_pl, st, q, v))(
-            state_pl, chunk, nv)
+    jaxpr_spl = jax.make_jaxpr(pipe_pl._session_step_q)(state_pl, chunk, nv)
     targets.append(Target(
         name="stream_pallas", jaxpr=jaxpr_spl, numerics="fixed",
         n_samples=CHUNK_LEN,

@@ -370,12 +370,13 @@ class InFilterPipeline:
         registers (delay slice at offset 0 re-reads the old delays; masked
         HWR sums vanish), which is what makes padding slots inert.
 
-        ``config.stream_impl`` selects the octave-cascade hot path: "xla"
-        splices [delay, chunk] per octave in XLA (below); "pallas" runs
-        ``kernels.fir_mp_stream``, a stateful kernel that carries the delay
-        lines / accumulators / running amax in VMEM scratch across its
+        ``config.stream_impl`` selects the octave-cascade hot path
+        (``_cascade``): "xla" splices [delay, chunk] per octave in XLA;
+        "pallas" runs the stateful streaming kernel (``kernels.fir_mp_stream``
+        in float, ``kernels.fir_mp_stream_q`` in fixed), which carries the
+        delay lines / accumulators / running amax in VMEM scratch across its
         chunk-block grid. Both run the same solver math in the same blocked
-        accumulation order, so in interpret mode they agree bit-for-bit.
+        accumulation order, so they agree bit-for-bit.
 
         The step's ops carry the named scopes ``session_step`` and, inside
         it, ``quantize``, ``octave_cascade`` and ``readout`` (in both
@@ -388,24 +389,12 @@ class InFilterPipeline:
 
     def _session_step_float(self, state: SessionState, chunk: jax.Array,
                             valid: jax.Array):
-        c = self.config
         S, L = chunk.shape
         n = jnp.where(state.active, jnp.asarray(valid, jnp.int32), 0)
         if L > 0:
             pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
             chunk = jnp.where(pos0 < n[:, None], chunk, 0)
-            with jax.named_scope("octave_cascade"):
-                if c.stream_impl == "pallas":
-                    state = self._cascade_pallas(state, chunk, n)
-                elif c.stream_impl == "xla":
-                    state = self._cascade_xla(state, chunk, n)
-                else:
-                    # a typo must not silently serve XLA results as "the
-                    # kernel"
-                    raise ValueError(f"unknown stream_impl "
-                                     f"{c.stream_impl!r}: expected 'xla' "
-                                     "or 'pallas'")
-        # a zero-length chunk is a pure readout: no register moves
+        state = self._cascade(state, chunk, n)
         with jax.named_scope("readout"):
             phi = (state.acc - self.mu) / self.sigma
             return state, km.forward(self.clf, phi, exact=False), phi
@@ -413,17 +402,10 @@ class InFilterPipeline:
     def _session_step_fixed(self, state: SessionState, chunk: jax.Array,
                             valid: jax.Array):
         """The int32 session step: quantize the chunk onto the static ADC
-        grid, zero invalid positions, and run the integer cascade — every
-        register stays on the fixed-point grid and chunked decisions are
-        bit-for-bit the one-shot program's. The kernel selection happens
-        HERE: "xla" runs ``fixed.cascade_q``; "pallas" runs the
-        VMEM-resident integer kernel (``kernels.fir_mp_stream_q``) —
-        bit-identical registers and decisions either way."""
+        grid, zero invalid positions, and run the integer step on the codes
+        — every register stays on the fixed-point grid and chunked
+        decisions are bit-for-bit the one-shot program's."""
         from repro.core import fixed
-        c = self.config
-        if c.stream_impl not in ("xla", "pallas"):
-            raise ValueError(f"unknown stream_impl {c.stream_impl!r}: "
-                             "expected 'xla' or 'pallas'")
         prog = self.fixed_program()
         S, L = chunk.shape
         n = jnp.where(state.active, jnp.asarray(valid, jnp.int32), 0)
@@ -434,71 +416,80 @@ class InFilterPipeline:
                 xq = fixed.quantize_signal(prog, chunk)
                 pos0 = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1)
                 xq = jnp.where(pos0 < n[:, None], xq, 0)
-        if c.stream_impl == "pallas":
-            state, p_q, phi_q = self._cascade_pallas_fixed(prog, state,
-                                                           xq, n)
-        else:
-            with jax.named_scope("octave_cascade"):
-                state = fixed.cascade_q(prog, state, xq, n)
-            p_q, phi_q = fixed.readout_q(prog, state.acc)
+        state, p_q, phi_q = self._session_step_q(state, xq, n)
         with jax.named_scope("readout"):
             return state, prog.out_spec.dequantize(p_q), \
                 prog.phi.dequantize(phi_q)
 
-    def _cascade_pallas_fixed(self, prog, state: SessionState,
-                              xq: jax.Array, n: jax.Array):
-        """Integer octave cascade through the stateful int Pallas kernel
-        (``kernels.fir_mp_stream_q``): the same registers-in-VMEM state
-        machine as the float ``_cascade_pallas``, on the fixed-point
-        datapath — bit-for-bit equal to ``fixed.session_step_q``."""
+    def _session_step_q(self, state: SessionState, xq: jax.Array,
+                        n: jax.Array):
+        """The integer step on ADC codes, ``fixed.session_step_q`` through
+        this pipeline's ``stream_impl``: the octave cascade, then the
+        integer readout. Returns ``(state', p_q, phi_q)``; the registers
+        and codes are bit-identical under either impl."""
+        from repro.core import fixed
+        state = self._cascade(state, xq, n)
+        p_q, phi_q = fixed.readout_q(self.fixed_program(), state.acc)
+        return state, p_q, phi_q
+
+    def _cascade(self, state: SessionState, chunk: jax.Array,
+                 n: jax.Array) -> SessionState:
+        """The octave cascade over one chunk (samples in float, ADC codes
+        in fixed), through the hot path ``config.stream_impl`` picks: "xla"
+        (``_cascade_xla``, or ``fixed.cascade_q``) or "pallas"
+        (``_cascade_pallas``). A zero-length chunk moves no register."""
         from repro.core import fixed
         c = self.config
-        if c.mode != "mp":
+        if c.stream_impl not in ("xla", "pallas"):
+            # a typo must not silently serve XLA results as "the kernel"
+            raise ValueError(f"unknown stream_impl {c.stream_impl!r}: "
+                             "expected 'xla' or 'pallas'")
+        if c.stream_impl == "pallas" and c.mode != "mp":
             raise ValueError(
                 f"stream_impl='pallas' runs the MP streaming kernel; it has "
                 f"no {c.mode!r}-mode variant (use stream_impl='xla')")
-        if xq.shape[1] == 0:
-            # a zero-length chunk is a pure readout: no register moves
-            p_q, phi_q = fixed.readout_q(prog, state.acc)
-            return state, p_q, phi_q
-        from repro.kernels import fir_mp_stream_q
+        if chunk.shape[1] == 0:
+            return state
         with jax.named_scope("octave_cascade"):
-            delays, consumed, acc, amax = fir_mp_stream_q(
-                prog, xq, n, state.delays, state.consumed, state.acc,
-                state.amax)
-            state = state._replace(delays=delays, consumed=consumed,
-                                   acc=acc, amax=amax, count=state.count + n)
-        p_q, phi_q = fixed.readout_q(prog, acc)
-        return state, p_q, phi_q
+            if c.stream_impl == "pallas":
+                return self._cascade_pallas(state, chunk, n)
+            if c.numerics == "fixed":
+                return fixed.cascade_q(self.fixed_program(), state, chunk, n)
+            return self._cascade_xla(state, chunk, n)
 
     def _cascade_pallas(self, state: SessionState, chunk: jax.Array,
                         n: jax.Array) -> SessionState:
-        """Octave cascade through the stateful Pallas streaming kernel."""
+        """Octave cascade through the stateful Pallas streaming kernel:
+        ``kernels.fir_mp_stream_q`` on the integer codes in fixed numerics,
+        ``kernels.fir_mp_stream`` in float."""
+        from repro.kernels import fir_mp_stream, fir_mp_stream_q
         c = self.config
-        if c.mode != "mp":
-            raise ValueError(
-                f"stream_impl='pallas' runs the MP streaming kernel; it has "
-                f"no {c.mode!r}-mode variant (use stream_impl='xla')")
-        from repro.kernels import fir_mp_stream
-        if c.quant_bits is not None:
-            # quantization needs the post-update running amax BEFORE the
-            # filter pass, so it cannot fold into the kernel's single sweep
-            with jax.named_scope("quantize"):
-                amax = jnp.maximum(state.amax,
-                                   jnp.max(jnp.abs(chunk), axis=-1))
-                chunk = fbm.quant_signal(chunk, c, amax=amax)
-            update_amax = False
+        if c.numerics == "fixed":
+            delays, consumed, acc, amax = fir_mp_stream_q(
+                self.fixed_program(), chunk, n, state.delays, state.consumed,
+                state.acc, state.amax)
         else:
-            # raw path: the octave-0 kernel folds the running-amax update
-            # into its grid sweep (one HBM read serves filter AND calibrate)
-            amax = state.amax
-            update_amax = True
-        delays, consumed, acc, amax = fir_mp_stream(
-            chunk, n, state.delays, state.consumed, state.acc, amax,
-            self.bp_taps, self.lp_taps, c.gamma_f, solver=c.solver,
-            update_amax=update_amax)
-        return SessionState(delays, consumed, acc, amax,
-                            state.count + n, state.active)
+            if c.quant_bits is not None:
+                # quantization needs the post-update running amax BEFORE
+                # the filter pass, so it cannot fold into the kernel's
+                # single sweep
+                with jax.named_scope("quantize"):
+                    amax = jnp.maximum(state.amax,
+                                       jnp.max(jnp.abs(chunk), axis=-1))
+                    chunk = fbm.quant_signal(chunk, c, amax=amax)
+                update_amax = False
+            else:
+                # raw path: the octave-0 kernel folds the running-amax
+                # update into its grid sweep (one HBM read serves filter
+                # AND calibrate)
+                amax = state.amax
+                update_amax = True
+            delays, consumed, acc, amax = fir_mp_stream(
+                chunk, n, state.delays, state.consumed, state.acc, amax,
+                self.bp_taps, self.lp_taps, c.gamma_f, solver=c.solver,
+                update_amax=update_amax)
+        return state._replace(delays=delays, consumed=consumed, acc=acc,
+                              amax=amax, count=state.count + n)
 
     def _cascade_xla(self, state: SessionState, chunk: jax.Array,
                      n: jax.Array) -> SessionState:

@@ -14,16 +14,18 @@ Kernels:
   fir_mp_bank  - multi-filter fir_mp: grid (batch_tile, filter) with the
                  filter axis innermost so one VMEM-resident signal block
                  serves a whole octave's filter set in a single pallas_call
-  fir_mp_stream - stateful session-step kernel: grid (slot, chunk_block,
-                 filter) carrying each slot's FIR delay line, per-band
-                 accumulators and running amax in VMEM scratch across grid
-                 steps (the step()-shaped streaming hot path; bit-identical
-                 to the XLA session step in interpret mode)
-  fir_mp_bank_q / fir_mp_stream_q - the INTEGER twins of the two fused
-                 kernels: the bit-true fixed-point datapath (integer MP
-                 bisection, shift/add/compare only) on the same grids,
-                 bit-for-bit equal to the fxp_* XLA kernels and censused
-                 multiplier-free by benchmarks/hardware_cost.py
+  fir_mp_bank_q - the INTEGER twin of fir_mp_bank: the bit-true
+                 fixed-point datapath (integer MP bisection,
+                 shift/add/compare only) on the same grid
+  fir_mp_stream / fir_mp_stream_q - one stateful session-step kernel:
+                 grid (slot, chunk_block, filter) carrying each slot's FIR
+                 delay line, per-band accumulators and running amax in
+                 VMEM scratch across grid steps, with a float and an
+                 integer datapath (the step()-shaped streaming hot path;
+                 bit-identical to the XLA session step of either numerics)
+
+The integer kernels are bit-for-bit equal to the fxp_* XLA kernels and
+censused multiplier-free by benchmarks/hardware_cost.py.
 
 Default block shapes come from the committed autotune table
 (stream_shapes.json, refreshed by benchmarks/kernel_sweep.py).

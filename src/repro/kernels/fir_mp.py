@@ -14,26 +14,29 @@ Optionally fuses the paper's entire in-filter readout
 so one HBM read of the signal produces the scalar kernel feature directly —
 the TPU analogue of the FPGA's per-band accumulator register.
 
-Four kernel families live here, two grid layouts:
+Two grid layouts live here:
 
 * one-shot (``fir_mp_pallas`` / ``fir_mp_bank_pallas``): grid over
   (batch_tile,) or (batch_tile, filter) — the whole signal row is resident
   per step; block holds (block_b, N) rows in VMEM (1 s @ 16 kHz f32 =
-  64 KiB/row; block_b=8 -> 0.5 MiB).
+  64 KiB/row; block_b=8 -> 0.5 MiB). The bank kernel has an integer twin,
+  ``fir_mp_bank_q_pallas``.
 * streaming (``fir_mp_stream_octave``): grid (slot_tile, chunk_block,
   filter) — per-slot FIR delay lines, partial accumulators and running
-  amax carried in VMEM scratch across the chunk_block axis.
+  amax carried in VMEM scratch across the chunk_block axis. One kernel
+  body serves both numerics; its datapath (``FloatPath`` or
+  ``FixedPath``) holds what differs.
 
-Each has an integer twin (``fir_mp_bank_q_pallas`` /
-``fir_mp_stream_octave_q``) executing ``repro.core.fixed``'s bit-true
-fixed-point datapath — integer bisection, shift/add/compare only — on the
-same grids, bit-for-bit equal to the ``fxp_*`` XLA kernels on either
-carrier (int32, or f32-carried integer codes).
+The integer kernels execute ``repro.core.fixed``'s bit-true fixed-point
+datapath — integer bisection, shift/add/compare only — bit-for-bit equal
+to the ``fxp_*`` XLA kernels on either carrier (int32, or f32-carried
+integer codes).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -238,6 +241,13 @@ def _slide(x, v, vmax: int):
     return x
 
 
+def _splice(delay, blk, M: int):
+    """An M-tap FIR's input over a block: the delay line's last M - 1
+    samples, then the block."""
+    return jnp.concatenate([delay[:, delay.shape[1] - (M - 1):], blk],
+                           axis=1)
+
+
 def _fir_windows(buf, M: int, n: int) -> list:
     """FIR windows as M lane slices: ``w[k][:, j] = buf[:, j + k]``."""
     return [buf[:, k:k + n] for k in range(M)]
@@ -269,214 +279,6 @@ def _add_column(part, f, s):
     every other column keeps its bits."""
     col = jax.lax.broadcasted_iota(jnp.int32, part.shape, 1) == f
     return jnp.where(col, part + s, part)
-
-
-def _stream_specs(bs, LB, T1, F, emit_next):
-    """BlockSpecs shared by both streaming kernels: slot-block rows, the
-    signal block per chunk block, whole-row state, and the tap ROMs and
-    gamma whole in SMEM (read as scalars)."""
-    row = lambda w: pl.BlockSpec((bs, w), lambda i, b, f: (i, 0))
-    in_specs = [
-        pl.BlockSpec((bs, LB), lambda i, b, f: (i, b)),   # signal
-        row(1),                                           # valid counts
-        row(1),                                           # decim phase
-        row(T1),                                          # delay line
-        row(F),                                           # accumulators
-        row(1),                                           # running amax
-    ]
-    out_specs = [row(F), row(T1), row(1)]
-    if emit_next:
-        out_specs.append(pl.BlockSpec((bs, LB // 2),
-                                      lambda i, b, f: (i, b)))
-    return in_specs, out_specs
-
-
-_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
-
-# scratch is carried across grid steps -> every axis must iterate
-# sequentially on TPU (no parallel partitioning of the grid)
-_SEQUENTIAL = pltpu.CompilerParams(
-    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
-
-
-# ---------------------------------------------------------------------------
-# fir_mp_stream: stateful session-step kernel
-# ---------------------------------------------------------------------------
-
-
-def _fir_mp_stream_kernel(gamma_ref, h_ref, lp_ref, x_ref, n_ref, start_ref,
-                          delay_ref, acc_ref, amax_ref, *refs,
-                          solver, scale, emit_next, update_amax,
-                          T1, M, M_lp, LB):
-    """One grid step of the streaming octave kernel.
-
-    Grid is (slot_block, chunk_block, filter) with filter INNERMOST: the
-    (bs, LB) signal block's index map is constant across the F filter steps,
-    so Pallas keeps it VMEM-resident while the kernel reads filter f's taps
-    from the whole (F, M) tap ROM in SMEM. The slot state — FIR delay line,
-    per-band partial accumulators, running amax — lives in VMEM scratch and
-    is carried across the chunk_block axis: the chunk streams through VMEM
-    block by block with NO per-block HBM state round-trip; state is read
-    once at grid start and written once at the final step.
-
-    Bit-parity with the XLA session step is by construction: the same
-    solver (``mp.mp_dot_fast_terms``, the tap-unrolled ``_mp_dot_fast``)
-    runs on the same window values, and the HWR sums use the shared
-    ``accumulate_block_len`` blocking with ``tree_sum``'s tree, added in
-    ascending block order exactly like ``filterbank.hwr_accumulate``.
-    """
-    if emit_next:
-        out_acc_ref, out_delay_ref, out_amax_ref, out_next_ref = refs[:4]
-        delay_s, part_s, amax_s = refs[4:]
-    else:
-        out_acc_ref, out_delay_ref, out_amax_ref = refs[:3]
-        delay_s, part_s, amax_s = refs[3:]
-
-    b = pl.program_id(1)
-    f = pl.program_id(2)
-    NB = pl.num_programs(1)
-    F = pl.num_programs(2)
-
-    @pl.when((b == 0) & (f == 0))
-    def _init():
-        delay_s[...] = delay_ref[...]
-        part_s[...] = jnp.zeros_like(part_s)
-        amax_s[...] = amax_ref[...]
-
-    blk = x_ref[...]                              # (bs, LB)
-    nv = n_ref[...]                               # (bs, 1) valid counts
-    gamma = gamma_ref[0]
-    delay = delay_s[...]                          # (bs, T1)
-
-    if update_amax:
-        # running amax: invalid tails were zeroed upstream, and the padded
-        # tail block is zeros, so blockwise max == whole-row max (max is
-        # exactly associative; all operands >= +0.0).
-        @pl.when(f == 0)
-        def _amax():
-            amax_s[...] = jnp.maximum(
-                amax_s[...],
-                jnp.max(jnp.abs(blk), axis=-1, keepdims=True))
-
-    # --- band-pass filter f over this block -------------------------------
-    bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
-    taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
-    y = mp_mod.mp_dot_fast_terms(_fir_windows(bufv, M, LB), taps, gamma,
-                                 solver)
-    pos = b * LB + jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
-    hwr = jnp.where(pos < nv, jnp.maximum(y, 0.0), 0.0)
-    part_s[...] = _add_column(part_s[...], f, _lane_tree_sum(hwr, LB))
-
-    @pl.when(f == F - 1)
-    def _block_tail():
-        # LP + ÷2 decimation for the next octave: solve ONLY the kept
-        # positions. LB is even, so each slot's keep-parity (its decimator
-        # phase) is constant across blocks; kept j of block b lands at
-        # out position b*LB/2 + j.
-        if emit_next:
-            bufl = jnp.concatenate([delay[:, T1 - (M_lp - 1):], blk], axis=1)
-            winl = _decim_windows(bufl, start_ref[...], M_lp, LB // 2)
-            lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
-            out_next_ref[...] = mp_mod.mp_dot_fast_terms(winl, lp, gamma,
-                                                         solver)
-        # slide the delay line by this block's VALID sample count; a
-        # zero-valid (masked/inert) slot slides by 0 and keeps its
-        # registers bit-identical.
-        v = jnp.clip(nv - b * LB, 0, LB)
-        delay_s[...] = _slide_delay(delay, blk, v, T1, LB)
-
-    @pl.when((b == NB - 1) & (f == F - 1))
-    def _flush():
-        out_acc_ref[...] = acc_ref[...] + part_s[...] * scale
-        out_delay_ref[...] = delay_s[...]
-        out_amax_ref[...] = amax_s[...]
-
-
-def fir_mp_stream_octave(
-    x: jax.Array,
-    n: jax.Array,
-    start: jax.Array,
-    delay: jax.Array,
-    acc: jax.Array,
-    amax: jax.Array,
-    H: jax.Array,
-    lp: jax.Array,
-    gamma: jax.Array,
-    *,
-    scale: float = 1.0,
-    solver: str = "newton",
-    emit_next: bool = True,
-    update_amax: bool = False,
-    block_s: int = 8,
-    interpret: bool = False,
-    octave: int = 0,
-):
-    """One octave of the stateful streaming step, as a single pallas_call
-    named ``fir_mp_stream_o<octave>``.
-
-    x (S, L): this octave's chunk (invalid tails already zeroed/masked
-    upstream); n (S,): per-slot valid counts; start (S,): per-slot decimator
-    phase (consumed % 2); delay (S, T1): FIR delay line registers; acc
-    (S, F): this octave's accumulator columns; amax (S,): running amax
-    (updated in-kernel only when ``update_amax``); H (F, M): band-pass taps;
-    lp (M_lp,): anti-aliasing taps (ignored unless ``emit_next``).
-
-    Returns ``(acc', delay', amax', y_next | None)`` where ``y_next`` is
-    (S, ceil(L/LB) * LB//2) — slice to ``(L+1)//2`` for the next octave.
-    """
-    S, L = x.shape
-    F, M = H.shape
-    T1 = delay.shape[1]
-    (M_lp,) = lp.shape
-    LB = accumulate_block_len(L)
-    NB = -(-L // LB)
-    bs = min(block_s, S)
-    s_pad = (-S) % bs
-    Sp = S + s_pad
-    dt = x.dtype
-
-    xp = jnp.pad(x, ((0, s_pad), (0, NB * LB - L)))
-    pad1 = lambda a: jnp.pad(a, ((0, s_pad),))
-    n2 = pad1(n.astype(jnp.int32))[:, None]
-    start2 = pad1(start.astype(jnp.int32))[:, None]
-    delay_p = jnp.pad(delay, ((0, s_pad), (0, 0)))
-    acc_p = jnp.pad(acc, ((0, s_pad), (0, 0)))
-    amax2 = pad1(amax.astype(dt))[:, None]
-    gamma1 = jnp.asarray(gamma, dtype=dt).reshape(1)
-
-    out_shape = [
-        jax.ShapeDtypeStruct((Sp, F), dt),             # acc'
-        jax.ShapeDtypeStruct((Sp, T1), dt),            # delay'
-        jax.ShapeDtypeStruct((Sp, 1), dt),             # amax'
-    ]
-    if emit_next:
-        out_shape.append(jax.ShapeDtypeStruct((Sp, NB * (LB // 2)), dt))
-    in_specs, out_specs = _stream_specs(bs, LB, T1, F, emit_next)
-
-    outs = pl.pallas_call(
-        functools.partial(_fir_mp_stream_kernel, solver=solver, scale=scale,
-                          emit_next=emit_next, update_amax=update_amax,
-                          T1=T1, M=M, M_lp=M_lp, LB=LB),
-        name=f"fir_mp_stream_o{octave}",
-        grid=(Sp // bs, NB, F),
-        in_specs=[_SMEM, _SMEM, _SMEM] + in_specs,   # gamma, BP taps, LP
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
-            pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
-            pltpu.VMEM((bs, 1), dt),     # running amax
-        ],
-        compiler_params=_SEQUENTIAL,
-        interpret=interpret,
-    )(gamma1, H.astype(dt), lp.astype(dt), xp, n2, start2, delay_p, acc_p,
-      amax2)
-
-    acc_o = outs[0][:S]
-    delay_o = outs[1][:S]
-    amax_o = outs[2][:S, 0]
-    y_next = outs[3][:S] if emit_next else None
-    return acc_o, delay_o, amax_o, y_next
 
 
 def fir_mp_pallas(
@@ -531,10 +333,11 @@ def fir_mp_pallas(
 
 
 # ---------------------------------------------------------------------------
-# integer (fixed-point) kernels: the bit-true hardware twin, VMEM-resident
+# integer (fixed-point) solves: the bit-true hardware twin, VMEM-resident
 # ---------------------------------------------------------------------------
 #
-# Both kernels below run repro.core.fixed's datapath INSIDE the pallas_call:
+# The bank kernel below and the streaming kernel's integer datapath
+# (``FixedPath``) run repro.core.fixed's datapath INSIDE the pallas_call:
 # integer bisection (arithmetic-shift midpoints, exact integer constraint
 # sums), saturating clamps onto static spec bounds, and integer HWR
 # accumulation. They are carrier-generic like every fxp_* kernel: on int32
@@ -697,41 +500,164 @@ def _fxp_mp_dot_ops(xs, ws, *, gamma_q, iters, spec, into=None):
             - _fxp_mpabs_ops(vs, gamma_q, iters, v_ref))
 
 
-def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
-                            delay_ref, acc_ref, amax_ref, *refs,
-                            stage, next_qmin, next_qmax, emit_next,
-                            update_amax, T1, M, M_lp, LB):
-    """One grid step of the INTEGER streaming octave kernel.
+# ---------------------------------------------------------------------------
+# fir_mp_stream: the stateful session-step kernel, one body, two datapaths
+# ---------------------------------------------------------------------------
+#
+# A grid step does the same in both numerics: the slot state machine, the
+# valid mask and per-filter column add, the ÷2 decimator windows, the
+# delay-line slide and the flush. What differs by numerics is the
+# datapath, one object per octave: its SMEM operands (``roms``), extra
+# scratch, the band solve and its column sum, the LP/÷2 emit, the flush
+# scale and the kernel's name.
 
-    Same grid and VMEM-scratch state machine as ``_fir_mp_stream_kernel``
-    — (slot_block, chunk_block, filter), filter innermost, delay line /
-    per-band partial accumulators / running amax carried in scratch across
-    the chunk_block axis — but every op is the fixed-point datapath:
 
-    * window codes rescale onto the band grid by ``stage.sig_shift``
-      (a static shift), once a block, into scratch that the block's filter
+class FloatPath(NamedTuple):
+    """The float32 datapath: ``mp.mp_dot_fast_terms`` (the tap-unrolled
+    ``_mp_dot_fast``) on in-register window slices, and each band's HWR sum
+    by ``tree_sum``'s lane tree.
+
+    Bit-parity with the XLA session step is by construction: the same
+    solver runs on the same window values, and the HWR sums use the shared
+    ``accumulate_block_len`` blocking with ``tree_sum``'s tree, added in
+    ascending block order exactly like ``filterbank.hwr_accumulate``.
+
+    SMEM holds gamma, the (F, M) band-pass taps ``bp`` and the (M_lp,)
+    anti-aliasing taps ``lp`` (None on the last octave). The flush scales
+    the partial sums by ``scale`` (the octave's ``2**o`` renorm)."""
+    bp: jax.Array
+    lp: jax.Array | None
+    gamma: jax.Array
+    solver: str = "newton"
+    scale: float = 1.0
+
+    name = "fir_mp_stream"
+
+    def roms(self, dt):
+        lp = jnp.zeros((1,), dt) if self.lp is None else self.lp
+        return [jnp.asarray(self.gamma, dtype=dt).reshape(1),
+                self.bp.astype(dt), lp.astype(dt)]
+
+    def scratch(self, M, bs, LB, dt):
+        return []
+
+    def band(self, h_ref, scratch, delay, blk, f, gamma):
+        M = h_ref.shape[1]
+        bufv = _splice(delay, blk, M)
+        taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
+        return mp_mod.mp_dot_fast_terms(_fir_windows(bufv, M, blk.shape[1]),
+                                        taps, gamma, self.solver)
+
+    def colsum(self, hwr):
+        return _lane_tree_sum(hwr, hwr.shape[1])
+
+    def emit(self, lp_ref, winl, gamma):
+        M_lp = lp_ref.shape[0]
+        lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
+        return mp_mod.mp_dot_fast_terms(winl, lp, gamma, self.solver)
+
+    def flush(self, part):
+        return part * self.scale
+
+
+class FixedPath(NamedTuple):
+    """The integer datapath: ``repro.core.fixed``'s bit-true fixed-point
+    solve, shift/add/compare only, on either carrier (int32, or f32-carried
+    integer codes):
+
+    * window codes rescale onto the band grid by ``stage.sig_shift`` (a
+      static shift), once a block, into scratch that the block's filter
       steps share; operand sums clamp onto the 10-bit internal specs, and
       each position solves by integer bisection (``fixed.fxp_mp_dot``) —
-      LSB-deterministic, so no float-style reduction-order bookkeeping is
-      needed anywhere;
-    * the flush applies ``stage.acc_shift`` as a left shift (the int mirror
-      of the float kernel's ``* 2**octave`` renorm — shifts distribute over
-      the partial sums, so flush-time shifting equals the XLA session
-      step's per-chunk shift bit-for-bit);
+      LSB-deterministic, so a band's HWR sum is a plain sum;
     * the decimator tail emits NEXT-OCTAVE register codes directly:
-      ``clamp(rescale(kept, lp_out_shift))`` onto [next_qmin, next_qmax]
-      happens in-kernel, so y_next needs no post-processing.
+      ``clamp(rescale(kept, lp_out_shift))`` onto ``next_spec`` happens
+      in-kernel, so y_next needs no post-processing;
+    * the flush applies ``stage.acc_shift`` as a left shift (the int mirror
+      of the float ``2**octave`` renorm — shifts distribute over the partial
+      sums, so flush-time shifting equals the XLA session step's per-chunk
+      shift bit-for-bit).
 
-    All gammas/iters/shifts/clamp bounds come from the compiled
-    ``fixed.OctaveStage`` — static ROM constants, never kernel operands;
-    the tap ROMs sit whole in SMEM and are read as scalars.
+    ``stage`` is the compiled :class:`repro.core.fixed.OctaveStage`: its
+    gammas, iteration counts, shifts and clamp bounds are static ROM
+    constants, never kernel operands; SMEM holds only its tap ROMs.
+    ``next_spec`` is the next octave's register spec (None on the last)."""
+    stage: fx.OctaveStage
+    next_spec: fx.FixedPointSpec | None = None
+
+    name = "fir_mp_stream_q"
+
+    def roms(self, dt):
+        lp = (jnp.zeros((1,), dt) if self.next_spec is None
+              else self.stage.lp_q[0].astype(dt))
+        return [self.stage.bp_q.astype(dt), lp]
+
+    def scratch(self, M, bs, LB, dt):
+        # the tap windows, carried across the block's filter steps, and
+        # the band-pass operands h + x and h - x
+        return [pltpu.VMEM((M, bs, LB), dt)] * 3
+
+    def band(self, h_ref, scratch, delay, blk, f):
+        # The M tap windows are unaligned lane slices of [delay tail, blk]
+        # and do not depend on the filter: build them once a block into
+        # scratch, lane-aligned. The solve reads them aligned and stores
+        # its operands for the loops to load, so no lane rotate runs inside
+        # either loop.
+        st = self.stage
+        win_s, u_s, v_s = scratch
+        M, LB = h_ref.shape[1], blk.shape[1]
+
+        @pl.when(f == 0)
+        def _windows():
+            bufv = _splice(delay, blk, M)
+            win_s[...] = jnp.stack([fx.rescale(w, st.sig_shift)
+                                    for w in _fir_windows(bufv, M, LB)])
+
+        win = win_s[...]
+        taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
+        return _fxp_mp_dot_ops([win[k] for k in range(M)], taps,
+                               gamma_q=st.gamma_bp, iters=st.iters_bp,
+                               spec=st.band_spec, into=(u_s, v_s))
+
+    def colsum(self, hwr):
+        return jnp.sum(hwr, axis=-1, keepdims=True)
+
+    def emit(self, lp_ref, winl):
+        st = self.stage
+        winl = [fx.rescale(w, st.lp_sig_shift) for w in winl]
+        M_lp = lp_ref.shape[0]
+        lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
+        kept = _fxp_mp_dot_ops(winl, lp, gamma_q=st.gamma_lp,
+                               iters=st.iters_lp, spec=st.lp_spec)
+        return jnp.clip(fx.rescale(kept, st.lp_out_shift),
+                        int(self.next_spec.qmin), int(self.next_spec.qmax))
+
+    def flush(self, part):
+        return fx.shift_left(part, self.stage.acc_shift)
+
+
+def _fir_mp_stream_kernel(*refs, path, n_roms, emit_next, update_amax):
+    """One grid step of the streaming octave kernel.
+
+    Grid is (slot_block, chunk_block, filter) with filter INNERMOST: the
+    (bs, LB) signal block's index map is constant across the F filter steps,
+    so Pallas keeps it VMEM-resident while the kernel reads filter f's taps
+    from the whole (F, M) tap ROM in SMEM. The slot state — FIR delay line,
+    per-band partial accumulators, running amax — lives in VMEM scratch and
+    is carried across the chunk_block axis: the chunk streams through VMEM
+    block by block with NO per-block HBM state round-trip; state is read
+    once at grid start and written once at the final step.
+
+    ``refs``: the ``n_roms`` SMEM operands (scalars, then the band-pass and
+    LP tap ROMs), the six input blocks, the three or four outputs, then the
+    scratch (delay line, partial sums, running amax, then ``path``'s own).
     """
-    if emit_next:
-        out_acc_ref, out_delay_ref, out_amax_ref, out_next_ref = refs[:4]
-        delay_s, part_s, amax_s, win_s, u_s, v_s = refs[4:]
-    else:
-        out_acc_ref, out_delay_ref, out_amax_ref = refs[:3]
-        delay_s, part_s, amax_s, win_s, u_s, v_s = refs[3:]
+    roms, refs = refs[:n_roms], refs[n_roms:]
+    *scalar_refs, h_ref, lp_ref = roms
+    x_ref, n_ref, start_ref, delay_ref, acc_ref, amax_ref = refs[:6]
+    out_acc_ref, out_delay_ref, out_amax_ref = refs[6:9]
+    out_next_ref = refs[9] if emit_next else None
+    delay_s, part_s, amax_s, *scratch = refs[9 + emit_next:]
 
     b = pl.program_id(1)
     f = pl.program_id(2)
@@ -744,56 +670,42 @@ def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
         part_s[...] = jnp.zeros_like(part_s)
         amax_s[...] = amax_ref[...]
 
-    blk = x_ref[...]                              # (bs, LB) register codes
+    blk = x_ref[...]                              # (bs, LB)
     nv = n_ref[...]                               # (bs, 1) valid counts
+    scalars = [r[0] for r in scalar_refs]         # gamma, in float
     delay = delay_s[...]                          # (bs, T1)
+    T1, LB = delay.shape[1], blk.shape[1]
 
     if update_amax:
-        # running max |code| telemetry (octave 0): invalid tails are zero
-        # codes and never raise the max — integer max is associative, so
-        # blockwise max == whole-chunk max
+        # running amax: invalid tails were zeroed upstream, and the padded
+        # tail block is zeros, so blockwise max == whole-row max (max is
+        # exactly associative; all operands >= 0).
         @pl.when(f == 0)
         def _amax():
             amax_s[...] = jnp.maximum(
                 amax_s[...],
                 jnp.max(jnp.abs(blk), axis=-1, keepdims=True))
 
-    # --- band-pass filter f over this block (integer MP solve) ------------
-    # The M tap windows are unaligned lane slices of [delay tail, blk] and
-    # do not depend on the filter: build them once a block into scratch,
-    # lane-aligned. The solve reads them aligned and stores its operands
-    # for the loops to load, so no lane rotate runs inside either loop.
-    @pl.when(f == 0)
-    def _windows():
-        bufv = jnp.concatenate([delay[:, T1 - (M - 1):], blk], axis=1)
-        win_s[...] = jnp.stack([fx.rescale(w, stage.sig_shift)
-                                for w in _fir_windows(bufv, M, LB)])
-
-    win = win_s[...]
-    taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
-    y = _fxp_mp_dot_ops([win[k] for k in range(M)], taps,
-                        gamma_q=stage.gamma_bp, iters=stage.iters_bp,
-                        spec=stage.band_spec, into=(u_s, v_s))
+    # --- band-pass filter f over this block -------------------------------
+    y = path.band(h_ref, scratch, delay, blk, f, *scalars)
     pos = b * LB + jax.lax.broadcasted_iota(jnp.int32, (1, LB), 1)
-    hwr = jnp.where(pos < nv, fx._relu(y), 0)
-    part_s[...] = _add_column(part_s[...], f,
-                              jnp.sum(hwr, axis=-1, keepdims=True))
+    # a Python zero of y's kind: an int 0 against float y would lower to
+    # an int constant and an in-kernel convert
+    zero = 0.0 if jnp.issubdtype(y.dtype, jnp.floating) else 0
+    hwr = jnp.where(pos < nv, jnp.maximum(y, zero), zero)
+    part_s[...] = _add_column(part_s[...], f, path.colsum(hwr))
 
     @pl.when(f == F - 1)
     def _block_tail():
-        # LP + ÷2 decimation: solve ONLY the kept positions (LB is even, so
-        # each slot's keep-parity is constant across blocks; kept j of
-        # block b lands at out position b*LB/2 + j), then requantize onto
-        # the next octave's register grid in-kernel.
+        # LP + ÷2 decimation for the next octave: solve ONLY the kept
+        # positions. LB is even, so each slot's keep-parity (its decimator
+        # phase) is constant across blocks; kept j of block b lands at
+        # out position b*LB/2 + j.
         if emit_next:
-            bufl = jnp.concatenate([delay[:, T1 - (M_lp - 1):], blk], axis=1)
-            winl = [fx.rescale(w, stage.lp_sig_shift) for w in
-                    _decim_windows(bufl, start_ref[...], M_lp, LB // 2)]
-            lp = [lp_ref[M_lp - 1 - k] for k in range(M_lp)]
-            kept = _fxp_mp_dot_ops(winl, lp, gamma_q=stage.gamma_lp,
-                                   iters=stage.iters_lp, spec=stage.lp_spec)
-            out_next_ref[...] = jnp.clip(
-                fx.rescale(kept, stage.lp_out_shift), next_qmin, next_qmax)
+            M_lp = lp_ref.shape[0]
+            winl = _decim_windows(_splice(delay, blk, M_lp), start_ref[...],
+                                  M_lp, LB // 2)
+            out_next_ref[...] = path.emit(lp_ref, winl, *scalars)
         # slide the delay line by this block's VALID sample count; a
         # zero-valid (masked/inert) slot slides by 0 and keeps its
         # registers bit-identical.
@@ -802,47 +714,49 @@ def _fir_mp_stream_q_kernel(h_ref, lp_ref, x_ref, n_ref, start_ref,
 
     @pl.when((b == NB - 1) & (f == F - 1))
     def _flush():
-        out_acc_ref[...] = acc_ref[...] + fx.shift_left(part_s[...],
-                                                        stage.acc_shift)
+        out_acc_ref[...] = acc_ref[...] + path.flush(part_s[...])
         out_delay_ref[...] = delay_s[...]
         out_amax_ref[...] = amax_s[...]
 
 
-def fir_mp_stream_octave_q(
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+# scratch is carried across grid steps -> every axis must iterate
+# sequentially on TPU (no parallel partitioning of the grid)
+_SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
+
+
+def fir_mp_stream_octave(
     x: jax.Array,
     n: jax.Array,
     start: jax.Array,
     delay: jax.Array,
     acc: jax.Array,
     amax: jax.Array,
+    path: FloatPath | FixedPath,
     *,
-    stage,
-    next_spec=None,
     emit_next: bool = True,
     update_amax: bool = False,
     block_s: int = 8,
     interpret: bool = False,
     octave: int = 0,
 ):
-    """One octave of the INTEGER streaming step, as a single pallas_call
-    named ``fir_mp_stream_q_o<octave>``.
+    """One octave of the stateful streaming step, as a single pallas_call
+    named ``<path.name>_o<octave>``: ``fir_mp_stream_o<octave>`` on the
+    float datapath, ``fir_mp_stream_q_o<octave>`` on the integer one.
 
-    x (S, L): this octave's chunk of register codes (invalid tails already
-    zeroed upstream); n (S,): per-slot valid counts; start (S,): per-slot
-    decimator phase (``consumed & 1``); delay (S, T1): delay-line register
-    codes; acc (S, F): 32-bit accumulator columns; amax (S,): running max
-    |code| (updated in-kernel only when ``update_amax`` — octave 0).
-    ``stage`` is the compiled :class:`repro.core.fixed.OctaveStage` (taps,
-    gammas, iteration counts, shifts and clamp bounds — all static);
-    ``next_spec`` the NEXT octave's register spec (required with
-    ``emit_next``).
+    x (S, L): this octave's chunk, samples or register codes (invalid tails
+    already zeroed upstream); n (S,): per-slot valid counts; start (S,):
+    per-slot decimator phase (``consumed & 1``); delay (S, T1): FIR delay
+    line registers; acc (S, F): this octave's accumulator columns; amax
+    (S,): running max |x| (updated in-kernel only when ``update_amax``).
+    ``path`` is the octave's datapath; with ``emit_next`` it has LP taps.
 
     Returns ``(acc', delay', amax', y_next | None)`` where ``y_next`` is
-    (S, ceil(L/LB) * LB//2) next-octave register codes — slice to
-    ``(L+1)//2``. Carrier-generic: int32 or f32-carried codes.
+    (S, ceil(L/LB) * LB//2) — slice to ``(L+1)//2`` for the next octave.
     """
     S, L = x.shape
-    F, M = stage.bp_q.shape
     T1 = delay.shape[1]
     LB = accumulate_block_len(L)
     NB = -(-L // LB)
@@ -850,14 +764,8 @@ def fir_mp_stream_octave_q(
     s_pad = (-S) % bs
     Sp = S + s_pad
     dt = x.dtype
-
-    if emit_next:
-        lp = stage.lp_q[0].astype(dt)            # (M_lp,)
-        next_qmin, next_qmax = int(next_spec.qmin), int(next_spec.qmax)
-    else:
-        lp = jnp.zeros((1,), dt)
-        next_qmin = next_qmax = 0
-    (M_lp,) = lp.shape
+    roms = path.roms(dt)
+    F, M = roms[-2].shape
 
     xp = jnp.pad(x, ((0, s_pad), (0, NB * LB - L)))
     pad1 = lambda a: jnp.pad(a, ((0, s_pad),))
@@ -867,36 +775,42 @@ def fir_mp_stream_octave_q(
     acc_p = jnp.pad(acc, ((0, s_pad), (0, 0)))
     amax2 = pad1(amax.astype(dt))[:, None]
 
+    # slot-block rows, the signal block per chunk block, whole-row state
+    row = lambda w: pl.BlockSpec((bs, w), lambda i, b, f: (i, 0))
+    in_specs = [
+        pl.BlockSpec((bs, LB), lambda i, b, f: (i, b)),   # signal
+        row(1),                                           # valid counts
+        row(1),                                           # decim phase
+        row(T1),                                          # delay line
+        row(F),                                           # accumulators
+        row(1),                                           # running amax
+    ]
+    out_specs = [row(F), row(T1), row(1)]
     out_shape = [
         jax.ShapeDtypeStruct((Sp, F), dt),             # acc'
         jax.ShapeDtypeStruct((Sp, T1), dt),            # delay'
         jax.ShapeDtypeStruct((Sp, 1), dt),             # amax'
     ]
     if emit_next:
+        out_specs.append(pl.BlockSpec((bs, LB // 2), lambda i, b, f: (i, b)))
         out_shape.append(jax.ShapeDtypeStruct((Sp, NB * (LB // 2)), dt))
-    in_specs, out_specs = _stream_specs(bs, LB, T1, F, emit_next)
 
     outs = pl.pallas_call(
-        functools.partial(_fir_mp_stream_q_kernel, stage=stage,
-                          next_qmin=next_qmin, next_qmax=next_qmax,
-                          emit_next=emit_next, update_amax=update_amax,
-                          T1=T1, M=M, M_lp=M_lp, LB=LB),
-        name=f"fir_mp_stream_q_o{octave}",
+        functools.partial(_fir_mp_stream_kernel, path=path, n_roms=len(roms),
+                          emit_next=emit_next, update_amax=update_amax),
+        name=f"{path.name}_o{octave}",
         grid=(Sp // bs, NB, F),
-        in_specs=[_SMEM, _SMEM] + in_specs,          # BP taps, LP taps
+        in_specs=[_SMEM] * len(roms) + in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
             pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
             pltpu.VMEM((bs, 1), dt),     # running amax
-            pltpu.VMEM((M, bs, LB), dt),  # tap windows, carried across filters
-            pltpu.VMEM((M, bs, LB), dt),  # band-pass operands h + x
-            pltpu.VMEM((M, bs, LB), dt),  # band-pass operands h - x
-        ],
+        ] + path.scratch(M, bs, LB, dt),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(stage.bp_q.astype(dt), lp, xp, n2, start2, delay_p, acc_p, amax2)
+    )(*roms, xp, n2, start2, delay_p, acc_p, amax2)
 
     acc_o = outs[0][:S]
     delay_o = outs[1][:S]

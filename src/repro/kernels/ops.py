@@ -13,10 +13,11 @@ Responsibilities:
     the mask than to store it).
 
 The integer wrappers (``fir_mp_bank_q*``, ``fir_mp_stream_q``) drive the
-fixed-point twins. ``fir_mp_stream_q`` is NOT itself jitted: it takes the
-compiled ``fixed.FixedPointProgram`` (host-side ROMs and shift tables), so
-— exactly like ``fixed.session_step_q`` — callers jit a closure over a
-concrete program.
+fixed-point datapaths; ``fir_mp_stream`` and ``fir_mp_stream_q`` share one
+streaming kernel and one per-octave cascade. ``fir_mp_stream_q`` is NOT
+itself jitted: it takes the compiled ``fixed.FixedPointProgram``
+(host-side ROMs and shift tables), so — exactly like
+``fixed.session_step_q`` — callers jit a closure over a concrete program.
 """
 
 from __future__ import annotations
@@ -177,8 +178,57 @@ def fir_mp_bank_accumulate(x: jax.Array, H: jax.Array, gamma,
 
 
 # ---------------------------------------------------------------------------
-# fir_mp_stream: the session-shaped streaming step
+# fir_mp_stream / fir_mp_stream_q: the session-shaped streaming step
 # ---------------------------------------------------------------------------
+
+
+def _stream_cascade(chunk, n, delays, consumed, acc, amax, paths, *,
+                    update_amax: bool, block_s: int | None):
+    """The per-octave loop of both streaming entry points: one
+    ``fir_mp_stream_octave`` pallas_call per octave, ``paths[o]`` its
+    datapath. Each call carries that octave's delay line, per-band
+    accumulator partials and (octave 0) running amax in VMEM scratch across
+    its chunk-block grid steps — the per-chunk state never round-trips
+    through HBM inside the step, and the [delay, chunk] splice happens in
+    VMEM rather than as an XLA concatenation. The decimated signal hops
+    octaves through HBM exactly once, like the XLA path's octave cascade.
+
+    The decimator phase is a bit-AND and the kept count a shift, not a
+    remainder and a divide (the counts are non-negative), so the integer
+    census stays divider-free, as in ``fixed.session_step_q``.
+    ``block_s=None`` takes the slot tile from ``stream_shapes`` under the
+    kernel's name."""
+    S, L = chunk.shape
+    if block_s is None:
+        block_s = best_block_s(paths[0].name, S)
+    x_o = chunk
+    n_o = jnp.asarray(n, jnp.int32)
+    l_o = L
+    new_delays, new_consumed, acc_cols = [], [], []
+    amax_out = amax
+    interpret = _interpret()
+    F = acc.shape[1] // len(paths)          # every octave has F bands
+    for o, path in enumerate(paths):
+        emit = o < len(paths) - 1
+        start_o = jnp.bitwise_and(consumed[o], 1).astype(jnp.int32)
+        acc_o = jax.lax.slice_in_dim(acc, o * F, (o + 1) * F, axis=1)
+        amax_in = amax if o == 0 else jnp.zeros((S,), chunk.dtype)
+        acc_new, delay_new, amax_new, y_next = _fir.fir_mp_stream_octave(
+            x_o, n_o, start_o, delays[o], acc_o, amax_in, path,
+            emit_next=emit, update_amax=(update_amax and o == 0),
+            block_s=block_s, interpret=interpret, octave=o)
+        if o == 0 and update_amax:
+            amax_out = amax_new
+        new_delays.append(delay_new)
+        new_consumed.append(consumed[o] + n_o)
+        acc_cols.append(acc_new)
+        if emit:
+            l_next = (l_o + 1) // 2
+            x_o = y_next[:, :l_next]
+            n_o = jnp.right_shift(jnp.maximum(n_o - start_o + 1, 0), 1)
+            l_o = l_next
+    return (tuple(new_delays), tuple(new_consumed),
+            jnp.concatenate(acc_cols, axis=1), amax_out)
 
 
 @functools.partial(jax.jit,
@@ -188,7 +238,8 @@ def fir_mp_stream(chunk: jax.Array, n: jax.Array, delays: tuple,
                   bp_taps: tuple, lp_taps: tuple, gamma, *,
                   solver: str = "newton", update_amax: bool = True,
                   block_s: int | None = None):
-    """Stateful multirate session step through the Pallas streaming kernel.
+    """Stateful multirate session step through the Pallas streaming kernel
+    on its float datapath (kernels ``fir_mp_stream_o<k>``).
 
     chunk (S, L): one slot-batched chunk, invalid tails already zeroed (and
     quantized, if deployed quantized — in which case pass the pre-updated
@@ -199,14 +250,6 @@ def fir_mp_stream(chunk: jax.Array, n: jax.Array, delays: tuple,
     accumulators, ``bp_taps[o]`` (F, M) / ``lp_taps[o]`` (M_lp,) the
     precomputed filters.
 
-    One pallas_call per octave; each carries that octave's delay line,
-    per-band accumulator partials, and (octave 0) running amax in VMEM
-    scratch across its chunk-block grid steps — the per-chunk state never
-    round-trips through HBM inside the step, and the [delay, chunk] splice
-    happens in VMEM rather than as an XLA concatenation. The decimated
-    signal hops octaves through HBM exactly once, like the XLA path's
-    octave cascade.
-
     Returns ``(delays', consumed', acc', amax')``. Masked slots (n == 0)
     are inert: their registers come back bit-identical (delay slides by 0,
     accumulator contributions are exactly +0.0). ``block_s=None`` (default)
@@ -214,44 +257,43 @@ def fir_mp_stream(chunk: jax.Array, n: jax.Array, delays: tuple,
     best-known slot tile at this capacity — shape choice never changes
     values, only VMEM tiling.
     """
-    num_octaves = len(delays)
-    S, L = chunk.shape
-    if block_s is None:
-        block_s = best_block_s("fir_mp_stream", S)
-    F = bp_taps[0].shape[0]
-    x_o = chunk
-    n_o = jnp.asarray(n, jnp.int32)
-    l_o = L
-    new_delays, new_consumed, acc_cols = [], [], []
-    amax_out = amax
-    interpret = _interpret()
-    for o in range(num_octaves):
-        start_o = jnp.remainder(consumed[o], 2).astype(jnp.int32)
-        emit = o < num_octaves - 1
-        lp = lp_taps[o] if emit else jnp.zeros((1,), chunk.dtype)
-        acc_o = jax.lax.slice_in_dim(acc, o * F, (o + 1) * F, axis=1)
-        amax_in = amax if o == 0 else jnp.zeros((S,), chunk.dtype)
-        acc_new, delay_new, amax_new, y_next = _fir.fir_mp_stream_octave(
-            x_o, n_o, start_o, delays[o], acc_o, amax_in, bp_taps[o], lp,
-            gamma, scale=2.0 ** o, solver=solver, emit_next=emit,
-            update_amax=(update_amax and o == 0), block_s=block_s,
-            interpret=interpret, octave=o)
-        if o == 0:
-            amax_out = amax_new if update_amax else amax
-        new_delays.append(delay_new)
-        new_consumed.append(consumed[o] + n_o)
-        acc_cols.append(acc_new)
-        if emit:
-            l_next = (l_o + 1) // 2
-            x_o = y_next[:, :l_next]
-            n_o = jnp.maximum(0, (n_o - start_o + 1) // 2)
-            l_o = l_next
-    return (tuple(new_delays), tuple(new_consumed),
-            jnp.concatenate(acc_cols, axis=1), amax_out)
+    last = len(delays) - 1
+    paths = [_fir.FloatPath(bp_taps[o], lp_taps[o] if o < last else None,
+                            gamma, solver, 2.0 ** o)
+             for o in range(len(delays))]
+    return _stream_cascade(chunk, n, delays, consumed, acc, amax, paths,
+                           update_amax=update_amax, block_s=block_s)
+
+
+def fir_mp_stream_q(prog, chunk_q: jax.Array, n: jax.Array, delays: tuple,
+                    consumed: tuple, acc: jax.Array, amax: jax.Array, *,
+                    block_s: int | None = None):
+    """Stateful INTEGER multirate session step through the Pallas streaming
+    kernel on its integer datapath (kernels ``fir_mp_stream_q_o<k>``): the
+    VMEM-resident twin of ``fixed.session_step_q``'s octave cascade.
+
+    ``prog`` is the compiled ``fixed.FixedPointProgram`` (static ROMs/shift
+    tables — which is why this wrapper is not itself jitted: jit a closure
+    over a concrete program, exactly like ``session_step_q``). ``chunk_q``
+    (S, L) is ADC codes with invalid tails already zeroed; ``n`` (S,)
+    effective valid counts; ``delays``/``consumed``/``acc``/``amax`` the
+    integer session registers. Requires mode "mp" and L >= 1 (the caller
+    handles the L == 0 pure-readout step).
+
+    Every in-kernel op is shift/add/compare, and the result registers are
+    bit-for-bit ``session_step_q``'s (and therefore bit-for-bit one-shot
+    ``infer_q`` under any chunking — the fixed-grid exactness argument in
+    docs/numerics.md). Returns ``(delays', consumed', acc', amax')``.
+    """
+    octaves = prog.bank.octaves
+    paths = [_fir.FixedPath(st, nxt.in_spec if nxt is not None else None)
+             for st, nxt in zip(octaves, octaves[1:] + (None,))]
+    return _stream_cascade(chunk_q, n, delays, consumed, acc, amax, paths,
+                           update_amax=True, block_s=block_s)
 
 
 # ---------------------------------------------------------------------------
-# integer (fixed-point) wrappers: the VMEM-resident hardware twin
+# integer (fixed-point) bank wrappers: the VMEM-resident hardware twin
 # ---------------------------------------------------------------------------
 
 
@@ -286,68 +328,3 @@ def fir_mp_bank_q_accumulate(xq: jax.Array, H_q: jax.Array, *, gamma_q: int,
                                   qmin=qmin, qmax=qmax, accumulate=True,
                                   interpret=_interpret())      # (B, F)
     return s.reshape(*lead, H_q.shape[0])
-
-
-def fir_mp_stream_q(prog, chunk_q: jax.Array, n: jax.Array, delays: tuple,
-                    consumed: tuple, acc: jax.Array, amax: jax.Array, *,
-                    block_s: int | None = None):
-    """Stateful INTEGER multirate session step through the Pallas kernels:
-    the VMEM-resident twin of ``fixed.session_step_q``'s octave cascade.
-
-    ``prog`` is the compiled ``fixed.FixedPointProgram`` (static ROMs/shift
-    tables — which is why this wrapper is not itself jitted: jit a closure
-    over a concrete program, exactly like ``session_step_q``). ``chunk_q``
-    (S, L) is ADC codes with invalid tails already zeroed; ``n`` (S,)
-    effective valid counts; ``delays``/``consumed``/``acc``/``amax`` the
-    integer session registers. Requires mode "mp" and L >= 1 (the caller
-    handles the L == 0 pure-readout step).
-
-    One pallas_call per octave, same state machine as the float
-    ``fir_mp_stream``; every in-kernel op is shift/add/compare, and the
-    result registers are bit-for-bit ``session_step_q``'s (and therefore
-    bit-for-bit one-shot ``infer_q`` under any chunking — the fixed-grid
-    exactness argument in docs/numerics.md). Returns
-    ``(delays', consumed', acc', amax')``.
-    """
-    bank = prog.bank
-    if bank.mode != "mp":
-        raise ValueError(
-            f"fir_mp_stream_q runs the MP streaming kernel; it has no "
-            f"{bank.mode!r}-mode variant (use fixed.session_step_q)")
-    S, L = chunk_q.shape
-    if block_s is None:
-        block_s = best_block_s("fir_mp_stream_q", S)
-    x_o = chunk_q
-    n_o = jnp.asarray(n, jnp.int32)
-    l_o = L
-    new_delays, new_consumed, acc_cols = [], [], []
-    amax_out = amax
-    interpret = _interpret()
-    col = 0
-    for o, st in enumerate(bank.octaves):
-        F = st.bp_q.shape[0]
-        emit = st.lp_q is not None
-        # parity phase by bit-AND, not remainder: the census stays
-        # divider-free (mirrors session_step_q)
-        start_o = jnp.bitwise_and(consumed[o], 1).astype(jnp.int32)
-        acc_o = jax.lax.slice_in_dim(acc, col, col + F, axis=1)
-        amax_in = amax if o == 0 else jnp.zeros((S,), chunk_q.dtype)
-        next_spec = bank.octaves[o + 1].in_spec if emit else None
-        acc_new, delay_new, amax_new, y_next = _fir.fir_mp_stream_octave_q(
-            x_o, n_o, start_o, delays[o], acc_o, amax_in, stage=st,
-            next_spec=next_spec, emit_next=emit, update_amax=(o == 0),
-            block_s=block_s, interpret=interpret, octave=o)
-        if o == 0:
-            amax_out = amax_new
-        new_delays.append(delay_new)
-        new_consumed.append(consumed[o] + n_o)
-        acc_cols.append(acc_new)
-        col += F
-        if emit:
-            l_next = (l_o + 1) // 2
-            x_o = y_next[:, :l_next]
-            # kept-count update: arithmetic shift, not an integer divide
-            n_o = jnp.right_shift(jnp.maximum(n_o - start_o + 1, 0), 1)
-            l_o = l_next
-    return (tuple(new_delays), tuple(new_consumed),
-            jnp.concatenate(acc_cols, axis=1), amax_out)

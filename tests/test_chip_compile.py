@@ -3,10 +3,11 @@
 The TPU compiler is installed with jaxlib and compiles for a topology that
 is described rather than attached. These tests compile, at the paper's
 full widths, what ``chip_smoke.py`` and the benchmark run on the chip:
-both streaming kernels, the batched session step in both numerics under
-both impls (and at the backlog cells' 4096-sample wave), the
-slot-sharded fixed step on a four-chip mesh, and the slot reset of
-``open()`` and ``close()``. Mosaic refuses shapes and
+the streaming kernel on both datapaths, the batched session step in both
+numerics under both impls (and at the backlog cells' 4096-sample wave),
+the slot-sharded fixed step on a four-chip mesh, and the slot reset of
+``open()`` and ``close()``; and they count the lane rotates Mosaic emits
+in the integer kernel's solve loops. Mosaic refuses shapes and
 ops that interpret mode accepts (unaligned blocks, lane gathers, strided
 lane slices), so these catch on the CPU what would otherwise fail on the
 chip. Nothing runs: a pass says the programs compile, not what they
@@ -22,6 +23,10 @@ from __future__ import annotations
 import importlib
 import json
 import os
+import pathlib
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +84,33 @@ def _fixed_program():
                          fixed_amax=4.0).fixed_program()
 
 
+def test_int_stream_kernel_band_loops_rotate_free(tmp_path):
+    """The integer kernel's band-pass bisection loops load their operands
+    aligned from VMEM scratch, so Mosaic emits no lane rotate in either
+    loop body (``scripts/kernel_llo.py``). The script switches Mosaic's
+    dump on through ``LIBTPU_INIT_ARGS``, which is read when the TPU
+    library loads: hence a fresh process. Only one process at a time may
+    load the library, so this test comes first in the file, before the
+    ``topo`` fixture loads it in this one."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [str(root / "src"), os.environ.get("PYTHONPATH", "")]))
+    run = subprocess.run(
+        [sys.executable, str(root / "scripts" / "kernel_llo.py"),
+         "--numerics", "fixed", "--dump", str(tmp_path)],
+        env=env, capture_output=True, text=True, timeout=600)
+    if run.returncode and "get_topology_desc" in run.stderr:
+        pytest.skip("no v5e:2x2 topology can be described here: "
+                    + run.stderr.strip().splitlines()[-1])
+    assert run.returncode == 0, run.stderr[-4000:]
+    loops = re.findall(r"loop \d+ \(one iteration\): \d+ ops, (\d+) lane "
+                       r"rotates", run.stdout)
+    # in program order: the band-pass solve's two bisection loops (h + x,
+    # h - x), then the LP/÷2 tail's two
+    assert len(loops) == 4, run.stdout
+    assert loops[:2] == ["0", "0"], run.stdout
+
+
 @pytest.mark.parametrize("chunk", [160, 320])
 def test_float_stream_kernel_compiles(one_chip, chunk):
     F, M = FILTERBANK.filters_per_octave, FILTERBANK.bp_taps
@@ -87,7 +119,8 @@ def test_float_stream_kernel_compiles(one_chip, chunk):
 
     def octave(x, n, start, delay, acc, amax, H, lp, gamma):
         return fir_mp.fir_mp_stream_octave(
-            x, n, start, delay, acc, amax, H, lp, gamma, update_amax=True,
+            x, n, start, delay, acc, amax, fir_mp.FloatPath(H, lp, gamma),
+            update_amax=True,
             block_s=stream_shapes.best_block_s("fir_mp_stream", S))
 
     c = jax.jit(octave).lower(
@@ -108,10 +141,11 @@ def test_int_stream_kernel_compiles(one_chip, chunk):
     T1 = max(FILTERBANK.bp_taps, FILTERBANK.lp_taps) - 1
     i32 = jnp.int32
 
+    path = fir_mp.FixedPath(st, prog.bank.octaves[1].in_spec)
+
     def octave(x, n, start, delay, acc, amax):
-        return fir_mp.fir_mp_stream_octave_q(
-            x, n, start, delay, acc, amax, stage=st,
-            next_spec=prog.bank.octaves[1].in_spec, update_amax=True,
+        return fir_mp.fir_mp_stream_octave(
+            x, n, start, delay, acc, amax, path, update_amax=True,
             block_s=stream_shapes.best_block_s("fir_mp_stream_q", S))
 
     c = jax.jit(octave).lower(

@@ -202,9 +202,7 @@ def test_census_pinned_pallas_stream():
     state = pipe.init_session(x.shape[0])
     xq = fixed.quantize_signal(prog, jnp.asarray(x[:, :CHUNK]))
     nv = jnp.full((x.shape[0],), CHUNK, jnp.int32)
-    jaxpr = jax.make_jaxpr(
-        lambda st, q, v: pipe._cascade_pallas_fixed(prog, st, q, v))(
-            state, xq, nv)
+    jaxpr = jax.make_jaxpr(pipe._session_step_q)(state, xq, nv)
     ir = build_program(jaxpr, name="stream_pallas")
     assert not ir.executable
     assert dict(census_program(ir)) == dict(census_jaxpr(jaxpr))
