@@ -388,8 +388,12 @@ def _count_terms(masks: list) -> jax.Array:
     return k
 
 
-def _mpabs_newton_terms(us: list, gamma, iters: int = DEFAULT_NEWTON_ITERS):
-    """``mpabs_newton`` with the operand axis unrolled into ``us``."""
+def _mpabs_newton_terms(us: list, gamma, iters: int = DEFAULT_NEWTON_ITERS,
+                        ref=None):
+    """``mpabs_newton`` with the operand axis unrolled into ``us``. Given
+    ``ref``, a (K, ...) VMEM ref, the loop's operands ``|u_k|`` are stored
+    there once and the loop body loads them from it, aligned, instead of
+    carrying the values into the loop."""
     dt = us[0].dtype
     gamma = jnp.asarray(gamma, dtype=dt)
     a = [jnp.abs(u) for u in us]
@@ -397,32 +401,42 @@ def _mpabs_newton_terms(us: list, gamma, iters: int = DEFAULT_NEWTON_ITERS):
     for t in a[1:]:
         z = jnp.maximum(z, t)
     z = z - gamma
+    if ref is not None:
+        ref[...] = jnp.stack(a)
 
     def body(_, z):
-        s = (tree_sum_terms([jnp.maximum(t - z, 0) for t in a])
-             + tree_sum_terms([jnp.maximum(-t - z, 0) for t in a]))
-        k = (_count_terms([t > z for t in a])
-             + _count_terms([-t > z for t in a])).astype(dt)
+        loaded = a if ref is None else ref[...]
+        a_k = [loaded[k] for k in range(len(a))]
+        s = (tree_sum_terms([jnp.maximum(t - z, 0) for t in a_k])
+             + tree_sum_terms([jnp.maximum(-t - z, 0) for t in a_k]))
+        k = (_count_terms([t > z for t in a_k])
+             + _count_terms([-t > z for t in a_k])).astype(dt)
         return z + (s - gamma) / jnp.maximum(k, 1.0)
 
     return jax.lax.fori_loop(0, iters, body, z)
 
 
-def _mpabs_bisect_terms(us: list, gamma,
-                        iters: int = DEFAULT_BISECT_ITERS) -> jax.Array:
-    """``mpabs(exact=False)`` with the operand axis unrolled into ``us``."""
+def _mpabs_bisect_terms(us: list, gamma, iters: int = DEFAULT_BISECT_ITERS,
+                        ref=None) -> jax.Array:
+    """``mpabs(exact=False)`` with the operand axis unrolled into ``us``.
+    Given ``ref``, a (K, ...) VMEM ref, ``us`` are stored there once and the
+    loop body loads them from it, aligned."""
     dt = us[0].dtype
     gamma = jnp.asarray(gamma, dtype=dt)
     hi = jnp.abs(us[0])
     for u in us[1:]:
         hi = jnp.maximum(hi, jnp.abs(u))
     lo = hi - gamma
+    if ref is not None:
+        ref[...] = jnp.stack(us)
 
     def body(_, state):
         lo, hi = state
         mid = (lo + hi) * jnp.asarray(0.5, dt)
-        h = (tree_sum_terms([jnp.maximum(u - mid, 0) for u in us])
-             + tree_sum_terms([jnp.maximum(-u - mid, 0) for u in us]))
+        loaded = us if ref is None else ref[...]
+        u_k = [loaded[k] for k in range(len(us))]
+        h = (tree_sum_terms([jnp.maximum(u - mid, 0) for u in u_k])
+             + tree_sum_terms([jnp.maximum(-u - mid, 0) for u in u_k]))
         too_low = h > gamma
         lo = jnp.where(too_low, mid, lo)
         hi = jnp.where(too_low, hi, mid)
@@ -432,16 +446,24 @@ def _mpabs_bisect_terms(us: list, gamma,
     return (lo + hi) * jnp.asarray(0.5, dt)
 
 
-def mp_dot_fast_terms(xs: list, ws: list, gamma, solver: str) -> jax.Array:
+def mp_dot_fast_terms(xs: list, ws: list, gamma, solver: str,
+                      into=None) -> jax.Array:
     """``_mp_dot_fast`` over unrolled operands: ``xs[k]`` is the k-th
     window column (any shape), ``ws[k]`` its tap (scalar or broadcastable).
-    Bitwise equal to ``_mp_dot_fast(stack(xs, -1), stack(ws), ...)``."""
+    Bitwise equal to ``_mp_dot_fast(stack(xs, -1), stack(ws), ...)``.
+
+    ``into`` = (u_ref, v_ref), two (K, ...) VMEM refs: each solve stores its
+    loop operands in its own, and its loop loads them from there, so no
+    operand is carried (and realigned) through a loop."""
     us = [w + x for x, w in zip(xs, ws)]
     vs = [w - x for x, w in zip(xs, ws)]
+    u_ref, v_ref = (None, None) if into is None else into
     if solver == "newton":
-        return _mpabs_newton_terms(us, gamma) - _mpabs_newton_terms(vs, gamma)
+        return (_mpabs_newton_terms(us, gamma, ref=u_ref)
+                - _mpabs_newton_terms(vs, gamma, ref=v_ref))
     if solver == "bisect":
-        return _mpabs_bisect_terms(us, gamma) - _mpabs_bisect_terms(vs, gamma)
+        return (_mpabs_bisect_terms(us, gamma, ref=u_ref)
+                - _mpabs_bisect_terms(vs, gamma, ref=v_ref))
     raise ValueError(f"unknown MP solver: {solver!r}")
 
 

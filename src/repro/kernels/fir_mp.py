@@ -253,6 +253,21 @@ def _fir_windows(buf, M: int, n: int) -> list:
     return [buf[:, k:k + n] for k in range(M)]
 
 
+def _block_windows(win_s, delay, blk, f, prep=None):
+    """The M tap windows of ``[delay tail, blk]``, loaded lane-aligned from
+    ``win_s`` (M, bs, LB). They are unaligned lane slices and do not depend
+    on the filter, so filter step 0 builds them into ``win_s`` once a block
+    (through ``prep``, if given) and every filter step reads them back."""
+    M, LB = win_s.shape[0], blk.shape[1]
+
+    @pl.when(f == 0)
+    def _windows():
+        win = _fir_windows(_splice(delay, blk, M), M, LB)
+        win_s[...] = jnp.stack(win if prep is None else [prep(w) for w in win])
+
+    return win_s[...]
+
+
 def _decim_windows(buf, start, M: int, n: int) -> list:
     """÷2 decimator windows: ``w[m][:, j] = buf[:, start + 2j + m]`` for
     ``j < n``, with the per-row phase ``start`` (rows, 1) in {0, 1}.
@@ -505,17 +520,21 @@ def _fxp_mp_dot_ops(xs, ws, *, gamma_q, iters, spec, into=None):
 # ---------------------------------------------------------------------------
 #
 # A grid step does the same in both numerics: the slot state machine, the
-# valid mask and per-filter column add, the ÷2 decimator windows, the
-# delay-line slide and the flush. What differs by numerics is the
-# datapath, one object per octave: its SMEM operands (``roms``), extra
-# scratch, the band solve and its column sum, the LP/÷2 emit, the flush
-# scale and the kernel's name.
+# valid mask and per-filter column add, the band solve's scratch (tap
+# windows built once a block, loop operands stored per filter), the ÷2
+# decimator windows, the delay-line slide and the flush. What differs by
+# numerics is the datapath, one object per octave: its SMEM operands
+# (``roms``), the band solve and its column sum, the LP/÷2 emit, the
+# flush scale and the kernel's name.
 
 
 class FloatPath(NamedTuple):
     """The float32 datapath: ``mp.mp_dot_fast_terms`` (the tap-unrolled
-    ``_mp_dot_fast``) on in-register window slices, and each band's HWR sum
-    by ``tree_sum``'s lane tree.
+    ``_mp_dot_fast``) on the block's tap windows, and each band's HWR sum
+    by ``tree_sum``'s lane tree. Like ``FixedPath``, it builds the windows
+    once a block into scratch, and each solve stores its loop operands
+    (``|h ± x|`` for Newton, ``h ± x`` for bisection) in scratch for its
+    loop to load aligned, so no lane rotate runs inside either loop.
 
     Bit-parity with the XLA session step is by construction: the same
     solver runs on the same window values, and the HWR sums use the shared
@@ -538,15 +557,13 @@ class FloatPath(NamedTuple):
         return [jnp.asarray(self.gamma, dtype=dt).reshape(1),
                 self.bp.astype(dt), lp.astype(dt)]
 
-    def scratch(self, M, bs, LB, dt):
-        return []
-
     def band(self, h_ref, scratch, delay, blk, f, gamma):
+        win_s, u_s, v_s = scratch
+        win = _block_windows(win_s, delay, blk, f)
         M = h_ref.shape[1]
-        bufv = _splice(delay, blk, M)
         taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
-        return mp_mod.mp_dot_fast_terms(_fir_windows(bufv, M, blk.shape[1]),
-                                        taps, gamma, self.solver)
+        return mp_mod.mp_dot_fast_terms([win[k] for k in range(M)], taps,
+                                        gamma, self.solver, into=(u_s, v_s))
 
     def colsum(self, hwr):
         return _lane_tree_sum(hwr, hwr.shape[1])
@@ -592,28 +609,14 @@ class FixedPath(NamedTuple):
               else self.stage.lp_q[0].astype(dt))
         return [self.stage.bp_q.astype(dt), lp]
 
-    def scratch(self, M, bs, LB, dt):
-        # the tap windows, carried across the block's filter steps, and
-        # the band-pass operands h + x and h - x
-        return [pltpu.VMEM((M, bs, LB), dt)] * 3
-
     def band(self, h_ref, scratch, delay, blk, f):
-        # The M tap windows are unaligned lane slices of [delay tail, blk]
-        # and do not depend on the filter: build them once a block into
-        # scratch, lane-aligned. The solve reads them aligned and stores
-        # its operands for the loops to load, so no lane rotate runs inside
-        # either loop.
+        # the solve reads the windows aligned and stores its operands for
+        # the loops to load, so no lane rotate runs inside either loop
         st = self.stage
         win_s, u_s, v_s = scratch
-        M, LB = h_ref.shape[1], blk.shape[1]
-
-        @pl.when(f == 0)
-        def _windows():
-            bufv = _splice(delay, blk, M)
-            win_s[...] = jnp.stack([fx.rescale(w, st.sig_shift)
-                                    for w in _fir_windows(bufv, M, LB)])
-
-        win = win_s[...]
+        win = _block_windows(win_s, delay, blk, f,
+                             lambda w: fx.rescale(w, st.sig_shift))
+        M = h_ref.shape[1]
         taps = [h_ref[f, M - 1 - k] for k in range(M)]   # conv tap order
         return _fxp_mp_dot_ops([win[k] for k in range(M)], taps,
                                gamma_q=st.gamma_bp, iters=st.iters_bp,
@@ -650,7 +653,8 @@ def _fir_mp_stream_kernel(*refs, path, n_roms, emit_next, update_amax):
 
     ``refs``: the ``n_roms`` SMEM operands (scalars, then the band-pass and
     LP tap ROMs), the six input blocks, the three or four outputs, then the
-    scratch (delay line, partial sums, running amax, then ``path``'s own).
+    scratch (delay line, partial sums, running amax, then the band solve's
+    tap windows and its two operand sets, which ``path.band`` fills).
     """
     roms, refs = refs[:n_roms], refs[n_roms:]
     *scalar_refs, h_ref, lp_ref = roms
@@ -807,7 +811,9 @@ def fir_mp_stream_octave(
             pltpu.VMEM((bs, T1), dt),    # delay line, carried across blocks
             pltpu.VMEM((bs, F), dt),     # per-band partial accumulators
             pltpu.VMEM((bs, 1), dt),     # running amax
-        ] + path.scratch(M, bs, LB, dt),
+            # the band solve's tap windows, and its operands from h + x
+            # and from h - x
+        ] + [pltpu.VMEM((M, bs, LB), dt)] * 3,
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
     )(*roms, xp, n2, start2, delay_p, acc_p, amax2)

@@ -7,10 +7,10 @@ the streaming kernel on both datapaths, the batched session step in both
 numerics under both impls (and at the backlog cells' 4096-sample wave),
 the slot-sharded fixed step on a four-chip mesh, and the slot reset of
 ``open()`` and ``close()``; and they count the lane rotates Mosaic emits
-in the integer kernel's solve loops. Mosaic refuses shapes and
-ops that interpret mode accepts (unaligned blocks, lane gathers, strided
-lane slices), so these catch on the CPU what would otherwise fail on the
-chip. Nothing runs: a pass says the programs compile, not what they
+in the kernel's band-pass solve loops on both datapaths. Mosaic refuses
+shapes and ops that interpret mode accepts (unaligned blocks, lane
+gathers, strided lane slices), so these catch on the CPU what would
+otherwise fail on the chip. Nothing runs: a pass says the programs compile, not what they
 compute or how fast.
 
 The topology is described inside a fixture, never at import: only one
@@ -84,20 +84,22 @@ def _fixed_program():
                          fixed_amax=4.0).fixed_program()
 
 
-def test_int_stream_kernel_band_loops_rotate_free(tmp_path):
-    """The integer kernel's band-pass bisection loops load their operands
-    aligned from VMEM scratch, so Mosaic emits no lane rotate in either
-    loop body (``scripts/kernel_llo.py``). The script switches Mosaic's
-    dump on through ``LIBTPU_INIT_ARGS``, which is read when the TPU
-    library loads: hence a fresh process. Only one process at a time may
-    load the library, so this test comes first in the file, before the
-    ``topo`` fixture loads it in this one."""
+@pytest.mark.parametrize("numerics", ["fixed", "float"])
+def test_int_stream_kernel_band_loops_rotate_free(tmp_path, numerics):
+    """On both datapaths the kernel's band-pass solve loops (integer
+    bisection, float Newton) load their operands aligned from VMEM
+    scratch, so Mosaic emits no lane rotate in either loop body
+    (``scripts/kernel_llo.py``). The script switches Mosaic's dump on
+    through ``LIBTPU_INIT_ARGS``, which is read when the TPU library
+    loads: hence a fresh process. Only one process at a time may load the
+    library, so this test comes first in the file, before the ``topo``
+    fixture loads it in this one."""
     root = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         [str(root / "src"), os.environ.get("PYTHONPATH", "")]))
     run = subprocess.run(
         [sys.executable, str(root / "scripts" / "kernel_llo.py"),
-         "--numerics", "fixed", "--dump", str(tmp_path)],
+         "--numerics", numerics, "--dump", str(tmp_path)],
         env=env, capture_output=True, text=True, timeout=600)
     if run.returncode and "get_topology_desc" in run.stderr:
         pytest.skip("no v5e:2x2 topology can be described here: "
@@ -105,8 +107,8 @@ def test_int_stream_kernel_band_loops_rotate_free(tmp_path):
     assert run.returncode == 0, run.stderr[-4000:]
     loops = re.findall(r"loop \d+ \(one iteration\): \d+ ops, (\d+) lane "
                        r"rotates", run.stdout)
-    # in program order: the band-pass solve's two bisection loops (h + x,
-    # h - x), then the LP/÷2 tail's two
+    # in program order: the band-pass solve's two loops (h + x, h - x),
+    # then the LP/÷2 tail's two
     assert len(loops) == 4, run.stdout
     assert loops[:2] == ["0", "0"], run.stdout
 

@@ -557,6 +557,28 @@ def test_fixed_pallas_multi_block_chunk_bitwise():
                                           err_msg=f"{name} diverged")
 
 
+@pytest.mark.parametrize("solver", ["newton", "bisect"])
+def test_float_pallas_multi_block_chunk_bitwise(solver):
+    """The float twin of ``test_fixed_pallas_multi_block_chunk_bitwise``: a
+    1,100-sample chunk spans three 512-sample blocks at octave 0, so the
+    float kernel rebuilds its window scratch per block and reuses it across
+    the filters of each, and each solve reloads its operands from scratch.
+    Slot 0's valid count ends mid-block, with decimator phases odd, even,
+    odd; slot 1 has 0 valid samples. Every register of the Pallas float
+    step equals the XLA float step's."""
+    px, pk = _pipes(solver=solver)
+    st = px.init_session(2)
+    st = st._replace(consumed=tuple(
+        c.at[0].set(k) for c, k in zip(st.consumed, (3, 2, 1))))
+    n = jnp.asarray([777, 0], jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(16), (2, 1100))
+    x = jnp.where(jnp.arange(x.shape[1])[None, :] < n[:, None], x, 0.0)
+    p_x, sx = _APP(px, x, st, valid=n)
+    p_k, sk = _APP(pk, x, st, valid=n)
+    np.testing.assert_array_equal(np.asarray(p_x), np.asarray(p_k))
+    _assert_states_bitwise(sx, sk, f"solver={solver}")
+
+
 def test_fixed_stream_server_end_to_end(tmp_path):
     """StreamServer serves numerics='fixed': open/feed/split/evict/reopen,
     with the final decision per stream equal (exactly — same codes, same
